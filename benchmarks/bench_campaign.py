@@ -65,12 +65,12 @@ PARITY_DEGRADATIONS = (
 )
 
 
-def _thr_cost() -> SimCostModel:
+def campaign_cost() -> SimCostModel:
     return SimCostModel(capacity_eps=4600.0, base_latency_s=0.5,
                         ckpt_duration_s=3.0, ckpt_sync_penalty=0.6)
 
 
-def _thr_lanes(n: int, horizon: int = THR_TICKS) -> list[LaneSpec]:
+def throughput_lanes(n: int, horizon: int = THR_TICKS) -> list[LaneSpec]:
     """n failure-bearing lanes sharing one λ array (the mega-campaign
     shape: many scenarios, one workload upload)."""
     rates = 3000.0 + 800.0 * np.sin(np.arange(horizon) / 40.0)
@@ -81,10 +81,10 @@ def _thr_lanes(n: int, horizon: int = THR_TICKS) -> list[LaneSpec]:
 
 def bench_throughput(lane_counts=THR_LANE_COUNTS,
                      horizon: int = THR_TICKS) -> list[dict]:
-    cost = _thr_cost()
+    cost = campaign_cost()
     rows = []
     for n in lane_counts:
-        lanes = _thr_lanes(n, horizon)
+        lanes = throughput_lanes(n, horizon)
         t0 = time.perf_counter()
         BatchedCampaign(cost, lanes, record_history=False).run()
         wall_np = time.perf_counter() - t0
@@ -132,7 +132,7 @@ def parity_lanes(horizon: int = 900) -> list[LaneSpec]:
     return lanes
 
 
-def _divergent_lanes(a: BatchedCampaign, b: DeviceCampaign) -> int:
+def divergent_lanes(a: BatchedCampaign, b: DeviceCampaign) -> int:
     """Count lanes that differ ANYWHERE: history, latency, recoveries, or
     final counters.  Bit-exact comparison — no tolerance."""
     n = a.n_lanes
@@ -150,11 +150,11 @@ def _divergent_lanes(a: BatchedCampaign, b: DeviceCampaign) -> int:
 
 
 def parity_check(horizon: int = 900) -> dict:
-    cost = _thr_cost()
+    cost = campaign_cost()
     lanes = parity_lanes(horizon)
     a = BatchedCampaign(cost, lanes).run()
     b = DeviceCampaign(cost, lanes).run()
-    div = _divergent_lanes(a, b)
+    div = divergent_lanes(a, b)
     out = {"lanes": len(lanes), "ticks": horizon,
            "divergent_lanes": div,
            "fma_contraction": bool(fma_contraction_active())}

@@ -66,6 +66,8 @@ import argparse
 import sys
 import time
 
+from repro.utils.compile_cache import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -76,6 +78,7 @@ def main() -> None:
                          "validation + end-to-end KhaosRuntime phase/"
                          "protocol gate (tier-1-adjacent check)")
     args = ap.parse_args()
+    use_compile_cache()
 
     # Pin the XLA CPU backend to a pre-FMA ISA BEFORE any bench initializes
     # a backend: the device campaign's bit-exact parity gate needs it

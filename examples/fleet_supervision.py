@@ -25,9 +25,11 @@ from repro.config import KhaosConfig
 from repro.data.stream import constant_rate, diurnal_rate
 from repro.fleet import FleetJobSpec, FleetSupervisor
 from repro.sim import SimCostModel
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     cost = SimCostModel(capacity_eps=2600.0, ckpt_duration_s=1.0,
                         state_bytes=2e9)
     kcfg = KhaosConfig(latency_constraint=1.5, recovery_constraint=240.0,

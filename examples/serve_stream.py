@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.models import zoo
 from repro.runtime.server import ServeRequest, StreamServer
+from repro.utils.compile_cache import use_compile_cache
 
 
 def serve_fleet_supervisor() -> dict:
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--fleet", action="store_true",
                     help="also run the fleet-supervisor control plane demo")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     params = zoo.init_params(cfg, jax.random.PRNGKey(0))

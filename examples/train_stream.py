@@ -21,6 +21,7 @@ from repro.core import KhaosRuntime, demo_prior_models
 from repro.data.stream import EventStream, diurnal_rate
 from repro.runtime import ResilientTrainer, TrainerConfig, TrainerJobHandle
 from repro.sim import SimCostModel
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
@@ -30,6 +31,7 @@ def main():
                     help="virtual seconds to run")
     ap.add_argument("--fail-at", type=float, default=35.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     stream = EventStream(schedule=diurnal_rate(base=400.0, period=600.0))

@@ -1,4 +1,4 @@
-from repro.checkpoint.store import CheckpointStore, CheckpointMeta, HAVE_ZSTD
+from repro.checkpoint.store import CheckpointStore, CheckpointMeta
 from repro.checkpoint.async_ckpt import AsyncCheckpointer, BackgroundCommitter
 from repro.checkpoint.incremental import IncrementalCheckpointer
 from repro.checkpoint.multilevel import MultiLevelCheckpointer
@@ -19,7 +19,7 @@ __all__ = [
     "BackgroundCommitter", "IncrementalCheckpointer",
     "MultiLevelCheckpointer", "CheckpointPolicy", "CheckpointManager",
     "Checkpointer", "CheckpointPlan", "SaveReport", "RestoreReport",
-    "HAVE_ZSTD", "ChunkedHostSnapshot", "DeltaLeafSource", "DeviceDeltaBase",
+    "ChunkedHostSnapshot", "DeltaLeafSource", "DeviceDeltaBase",
     "FlatLayout", "LeafSource", "PlainLeafSource", "as_leaf_source",
     "PeerReplicatedStore", "ReplicaStats", "ReplicationError",
     "retry_with_backoff", "ring_peers",

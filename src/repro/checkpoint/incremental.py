@@ -14,7 +14,7 @@ deltas compress hard.  Two encodings:
     ref.py oracle).  Lossy — used as a cheap level-1 in multi-level
     schemes (paper-cited [21]); never for the level-2 full snapshots.
 
-Compression: zstd when ``zstandard`` is installed, stdlib zlib otherwise.
+Compression: zstd, or stdlib zlib when the plan names it.
 The codec actually used is recorded in each delta manifest so restore picks
 the matching decompressor even if the environment changed in between.
 
@@ -332,9 +332,11 @@ def _decode_flat(ddir: str, flat: dict, mode: str, zero: frozenset,
         if device:
             from repro.kernels.ckpt_delta.ops import (default_interpret,
                                                       lossless_decode)
+            rows = (-1, GROUP)      # the kernels' layout: no relayout
             out_flat = np.asarray(lossless_decode(
-                base_flat, delta, resid,
-                interpret=default_interpret()))[:total]
+                base_flat.reshape(rows), delta.reshape(rows),
+                resid.reshape(rows),
+                interpret=default_interpret())).reshape(-1)[:total]
         else:
             from repro.kernels.ckpt_delta.ref import lossless_decode_ref
             out_flat = lossless_decode_ref(base_flat, delta, resid)
@@ -343,8 +345,8 @@ def _decode_flat(ddir: str, flat: dict, mode: str, zero: frozenset,
             from repro.kernels.ckpt_delta.ops import (default_interpret,
                                                       delta_decode)
             dflat = np.asarray(delta_decode(
-                arrays["q"], arrays["s"],
-                interpret=default_interpret()))[:total]
+                arrays["q"].reshape(-1, GROUP), arrays["s"],
+                interpret=default_interpret())).reshape(-1)[:total]
         else:
             from repro.kernels.ckpt_delta.ref import decode_ref
             dflat = decode_ref(arrays["q"], arrays["s"])[:total]

@@ -28,7 +28,7 @@ single protocol, configured by ``config.CheckpointPlan``:
            |                 encoded payload crosses the link — bytes_on_-
            |                 link drops to ~0.26x state bytes for int8
            |
-        compress           zstd when installed, zlib otherwise; the codec
+        compress           zstd, or zlib when the plan names it; the codec
            |                 used is recorded in the delta manifest
            |
         level routing      memory  — in-RAM snapshot, every trigger
@@ -68,7 +68,8 @@ from repro.checkpoint.incremental import (apply_delta, newest_delta_step,
                                           read_delta_manifest, write_delta)
 from repro.checkpoint.multilevel import allowed_levels
 from repro.checkpoint.pipeline import (ChunkedHostSnapshot, DeltaLeafSource,
-                                       DeviceDeltaBase, PlainLeafSource)
+                                       DeviceDeltaBase, PlainLeafSource,
+                                       prepare_device_encode)
 from repro.checkpoint.policy import CheckpointPolicy
 from repro.checkpoint.replication import PeerReplicatedStore
 from repro.checkpoint.store import CheckpointStore
@@ -195,6 +196,13 @@ class CheckpointManager:
         self.policy.mark(timestamp)
 
     # -- save ---------------------------------------------------------------
+    def prepare(self, state: Any) -> None:
+        """Compile ahead of time the device programs this plan's triggers
+        run on states shaped like ``state``, so no trigger's blocking time
+        includes a compile.  A job calls it before its clock starts."""
+        if self.plan.encode_placement == "device":
+            prepare_device_encode(state, self.plan.delta_codec)
+
     def _kind(self) -> str:
         if self._base is None:     # no live base: the chain must restart
             return "full"

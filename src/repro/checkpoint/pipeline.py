@@ -47,9 +47,12 @@ reader.
 Pack/refresh lifecycle: ``DeviceDeltaBase`` packs its flat buffer ONCE
 per full trigger/savepoint (``CheckpointManager`` refreshes it there and
 carries it across ``set_plan`` rebuilds via ``adopt_runtime_state``);
-every delta trigger then packs only the NEW state (one cached-jit
-dispatch) and encodes against the resident base, so the steady-state
-trigger never re-uploads or re-packs the base.
+every delta trigger then packs only the NEW state (one dispatch) and
+encodes against the resident base, so the steady-state trigger never
+re-uploads or re-packs the base.  The pack and the encoder are compiled
+ahead of time per layout (``prepare_device_encode``, which
+``CheckpointManager.prepare`` runs before a job's clock starts), so no
+trigger's blocking time includes a compile.
 
   * ``ChunkedHostSnapshot`` partitions the state's leaves into byte-bounded
     chunks.  Mutable host leaves (``np.ndarray``) are deep-copied eagerly —
@@ -81,6 +84,7 @@ trigger never re-uploads or re-packs the base.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -311,6 +315,63 @@ class FlatLayout:
                 for e in self.entries]
 
 
+def _spec(x: jax.Array) -> jax.ShapeDtypeStruct:
+    """``x``'s shape and dtype, and its placement when it is committed
+    (an uncommitted array may follow the other operands)."""
+    return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                sharding=x.sharding if x.committed else None)
+
+
+def _packable(state: Any) -> list:
+    """The (name, leaf) pairs the device plane packs: non-empty f32 device
+    leaves, in tree order."""
+    return [(name, leaf) for name, leaf in tree_flatten_with_names(state)
+            if isinstance(leaf, jax.Array) and leaf.size > 0
+            and np.dtype(leaf.dtype) == np.float32]
+
+
+@functools.lru_cache(maxsize=8)
+def _compiled_pack(specs: tuple):
+    from repro.kernels.ckpt_delta.ops import pack_flat
+    return pack_flat.lower(list(specs)).compile()
+
+
+@functools.lru_cache(maxsize=8)
+def _compiled_encoder(codec: str, new: jax.ShapeDtypeStruct,
+                      base: jax.ShapeDtypeStruct, num_leaves: int,
+                      interpret: bool):
+    from repro.kernels.ckpt_delta.ops import (flat_int8_encode,
+                                              flat_lossless_encode)
+    encode = flat_lossless_encode if codec == "lossless" else flat_int8_encode
+    group_leaf = jax.ShapeDtypeStruct((new.shape[0],), np.int32)
+    return encode.lower(new, base, group_leaf, num_leaves=num_leaves,
+                        interpret=interpret).compile()
+
+
+def pack(leaves: list) -> jax.Array:
+    """``pack_flat`` of ``leaves``, through the executable compiled for
+    their shapes and placements."""
+    return _compiled_pack(tuple(_spec(x) for x in leaves))(list(leaves))
+
+
+def prepare_device_encode(state: Any, codec: str) -> None:
+    """Compile ahead of time the pack and the flat ``codec`` encoder that
+    a device-encode trigger runs on ``state``'s f32 leaves.  Compiling
+    from shapes allocates nothing on the device."""
+    from repro.kernels.ckpt_delta.ops import default_interpret
+    leaves = [leaf for _, leaf in _packable(state)]
+    if not leaves:
+        return
+    specs = tuple(_spec(x) for x in leaves)
+    packer = _compiled_pack(specs)
+    # the packed buffer is committed exactly when a packed leaf is
+    committed = any(spec.sharding is not None for spec in specs)
+    flat = jax.ShapeDtypeStruct(
+        packer.out_info.shape, packer.out_info.dtype,
+        sharding=packer.output_shardings if committed else None)
+    _compiled_encoder(codec, flat, flat, len(leaves), default_interpret())
+
+
 class DeviceDeltaBase:
     """The delta base held device-resident across triggers — per-leaf refs
     (for the fallback path and shape checks) plus the PACKED flat
@@ -329,22 +390,17 @@ class DeviceDeltaBase:
     """
 
     def __init__(self, state: Any):
-        self.leaves: dict[str, Any] = {}
-        packable: list[tuple[str, Any]] = []
-        for name, leaf in tree_flatten_with_names(state):
-            if isinstance(leaf, jax.Array):
-                self.leaves[name] = leaf          # immutable: ref == copy
-                if np.dtype(leaf.dtype) == np.float32 and leaf.size > 0:
-                    packable.append((name, leaf))
-            else:
-                self.leaves[name] = np.array(leaf, copy=True)
+        self.leaves: dict[str, Any] = {
+            name: leaf if isinstance(leaf, jax.Array)     # immutable: ref
+            else np.array(leaf, copy=True)                # == copy
+            for name, leaf in tree_flatten_with_names(state)}
+        packable = _packable(state)
         self.layout: Optional[FlatLayout] = None
         self.flat: Optional[jax.Array] = None
         if packable:
-            from repro.kernels.ckpt_delta.ops import pack_flat
             self.layout = FlatLayout(
                 [(name, tuple(leaf.shape)) for name, leaf in packable])
-            self.flat = pack_flat([leaf for _, leaf in packable])
+            self.flat = pack([leaf for _, leaf in packable])
 
     def flat_subset(self, names: list) -> tuple[FlatLayout, jax.Array]:
         """The packed base restricted to ``names`` (in that order).  The
@@ -356,7 +412,7 @@ class DeviceDeltaBase:
         if names == self.layout.names:
             return self.layout, self.flat
         sub = FlatLayout([(n, self.layout.by_name[n].shape) for n in names])
-        parts = [self.flat[e.offset:e.offset + e.padded]
+        parts = [self.flat[e.offset // GROUP:(e.offset + e.padded) // GROUP]
                  for e in (self.layout.by_name[n] for n in names)]
         return sub, jax.numpy.concatenate(parts)
 
@@ -405,10 +461,7 @@ class DeltaLeafSource(LeafSource):
                  chunk_bytes: int = DEFAULT_CHUNK_BYTES,
                  interpret: Optional[bool] = None):
         assert codec in ("lossless", "int8"), codec
-        from repro.kernels.ckpt_delta.ops import (default_interpret,
-                                                  flat_int8_encode,
-                                                  flat_lossless_encode,
-                                                  pack_flat)
+        from repro.kernels.ckpt_delta.ops import default_interpret
         self.codec = codec
         self.interpret = default_interpret() if interpret is None \
             else interpret
@@ -448,12 +501,13 @@ class DeltaLeafSource(LeafSource):
 
         layout, base_flat = base.flat_subset([n for n, _ in packed])
         self.layout = layout
-        new_flat = pack_flat([leaf for _, leaf in packed])
+        new_flat = pack([leaf for _, leaf in packed])
+        encode = _compiled_encoder(codec, _spec(new_flat), _spec(base_flat),
+                                   len(packed), self.interpret)
         group_leaf = layout.group_leaf_device()
         if codec == "lossless":
-            d, r, leaf_changed, leaf_rnnz = flat_lossless_encode(
-                new_flat, base_flat, group_leaf, num_leaves=len(packed),
-                interpret=self.interpret)
+            d, r, leaf_changed, leaf_rnnz = encode(new_flat, base_flat,
+                                                   group_leaf)
             changed = np.asarray(leaf_changed)    # stats pull = device sync
             arrays: list[tuple[str, Any]] = []
             if changed.any():
@@ -463,9 +517,7 @@ class DeltaLeafSource(LeafSource):
                 else:           # residual known all-zero: skip its D2H —
                     self._payload["r"] = "zero"   # decoder reconstructs
         else:
-            q, s, leaf_changed = flat_int8_encode(
-                new_flat, base_flat, group_leaf, num_leaves=len(packed),
-                interpret=self.interpret)
+            q, s, leaf_changed = encode(new_flat, base_flat, group_leaf)
             changed = np.asarray(leaf_changed)    # stats pull = device sync
             arrays = [("q", q), ("s", s)] if changed.any() else []
         self.zero_names = tuple(
@@ -477,11 +529,13 @@ class DeltaLeafSource(LeafSource):
         chunk synchronously (the blocking cost), the rest on the pool."""
         tasks: list[tuple] = []
         for sfx, dev in arrays:
-            host = np.empty(int(dev.shape[0]), np.dtype(dev.dtype))
-            self._payload[sfx] = host
-            per = max(GROUP, chunk_bytes // host.itemsize)
-            for a in range(0, host.size, per):
-                tasks.append((host, dev, a, min(host.size, a + per)))
+            # rows of the (ng, GROUP) planes, or elements of the 1-D scales
+            host = np.empty(dev.shape, np.dtype(dev.dtype))
+            self._payload[sfx] = host.reshape(-1)      # a view: filled below
+            row_bytes = host[:1].nbytes
+            per = max(1, chunk_bytes // row_bytes)
+            for a in range(0, host.shape[0], per):
+                tasks.append((host, dev, a, min(host.shape[0], a + per)))
         if not tasks:
             return
         self._pull_chunk(*tasks[0])
@@ -491,7 +545,7 @@ class DeltaLeafSource(LeafSource):
 
     def _pull_chunk(self, host: np.ndarray, dev: Any, a: int, b: int) -> None:
         host[a:b] = np.asarray(dev[a:b])
-        self._account((b - a) * host.itemsize)
+        self._account(host[a:b].nbytes)
 
     def _account(self, nbytes: int) -> None:
         with self._link_lock:

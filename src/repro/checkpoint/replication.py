@@ -46,7 +46,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
-from repro.checkpoint.store import CheckpointStore
+from repro.checkpoint.store import CheckpointStore, as_leaf_dtype
 
 
 class ReplicationError(RuntimeError):
@@ -357,7 +357,7 @@ class PeerReplicatedStore(CheckpointStore):
         leaves_struct = jax.tree_util.tree_leaves(treedef_like)
         treedef = jax.tree_util.tree_structure(treedef_like)
         restored = [data[n] for n in names]
-        restored = [np.asarray(v, dtype=s.dtype) if hasattr(s, "dtype") else v
+        restored = [as_leaf_dtype(v, s.dtype) if hasattr(s, "dtype") else v
                     for v, s in zip(restored, leaves_struct)]
         return (jax.tree_util.tree_unflatten(treedef, restored),
                 manifest["extra"])

@@ -32,6 +32,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 import numpy as np
+import zstandard
 
 from repro.utils.trees import tree_flatten_with_names
 
@@ -39,21 +40,13 @@ import jax
 
 
 # ---------------------------------------------------------------------------
-# Compression codecs (zstd preferred, zlib always available)
+# Compression codecs: zstd by default, zlib when named explicitly
 # ---------------------------------------------------------------------------
 
-try:
-    import zstandard as _zstd
-    HAVE_ZSTD = True
-except ImportError:          # offline environments: stdlib fallback
-    _zstd = None
-    HAVE_ZSTD = False
-
-
 def resolve_codec(name: str = "auto") -> str:
-    """Map a requested codec name to an available one."""
+    """Map a requested codec name ("auto" means zstd) to a codec."""
     if name in ("auto", "zstd"):
-        return "zstd" if HAVE_ZSTD else "zlib"
+        return "zstd"
     if name != "zlib":
         raise ValueError(f"unknown codec {name!r}")
     return "zlib"
@@ -67,19 +60,26 @@ def get_compressor(name: str = "auto", level: int = 3
     if codec == "zstd":
         # fresh context per call: the pipelined writers compress leaves
         # concurrently on the io pool and zstd contexts are not thread-safe
-        return codec, lambda data: _zstd.ZstdCompressor(level=level).compress(data)
+        return codec, lambda data: zstandard.ZstdCompressor(
+            level=level).compress(data)
     return codec, lambda data: zlib.compress(data, level)
 
 
 def get_decompressor(name: str) -> Callable[[bytes], bytes]:
     """Decompressor for a codec name read back from a manifest."""
-    codec = resolve_codec(name)
-    if codec == "zstd":
-        if not HAVE_ZSTD:
-            raise RuntimeError("checkpoint was written with zstd but "
-                               "zstandard is not installed")
-        return lambda data: _zstd.ZstdDecompressor().decompress(data)
+    if resolve_codec(name) == "zstd":
+        return lambda data: zstandard.ZstdDecompressor().decompress(data)
     return zlib.decompress
+
+
+def as_leaf_dtype(v: np.ndarray, dtype) -> np.ndarray:
+    """A leaf read back from an ``.npz`` as ``dtype``.  NumPy writes the
+    dtypes it does not know (bfloat16 and the other ml_dtypes) as raw void
+    bytes of the same width, which are reinterpreted here, not cast."""
+    dtype = np.dtype(dtype)
+    if v.dtype.kind == "V" and v.dtype.itemsize == dtype.itemsize:
+        return v.view(dtype)
+    return np.asarray(v, dtype=dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -390,7 +390,7 @@ class CheckpointStore:
         leaves_struct = jax.tree_util.tree_leaves(treedef_like)
         treedef = jax.tree_util.tree_structure(treedef_like)
         restored = [data[n] for n in names]
-        restored = [np.asarray(v, dtype=s.dtype) if hasattr(s, "dtype") else v
+        restored = [as_leaf_dtype(v, s.dtype) if hasattr(s, "dtype") else v
                     for v, s in zip(restored, leaves_struct)]
         return jax.tree_util.tree_unflatten(treedef, restored), manifest["extra"]
 

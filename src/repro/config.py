@@ -292,7 +292,7 @@ class CheckpointPlan:
                                       # encoded payload (delta+sparse residual,
                                       # or int8 q+scales — ~4x fewer bytes)
                                       # crosses the device->host link
-    codec: str = "auto"               # auto | zstd | zlib (auto: zstd if installed)
+    codec: str = "auto"               # auto (= zstd) | zstd | zlib
     levels: Sequence[str] = ("local",)   # subset of {memory, local, remote}
     local_every: int = 1              # write local level every N triggers
     remote_every: int = 8             # write remote level every N triggers

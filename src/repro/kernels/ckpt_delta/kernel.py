@@ -37,69 +37,73 @@ Both encoders come in two granularities:
     group->leaf map.  That is how the skip-zero manifest markers and the
     residual-D2H skip survive the fusion of N kernel launches into one.
 
-  new, base  (N,)        viewed as (N/G, G); block (bg, G)
+Every kernel views its planes as (ng, GROUP) rows and walks them in
+blocks of ``bg`` rows.  Mosaic tiles a block's last two dims by (8, 128),
+so ``bg`` is either a multiple of 8 (the configured ``block_groups``) or
+the whole row count; a row count that ``bg`` does not divide leaves a
+ragged final block, whose out-of-range reads are undefined and whose
+out-of-range writes are dropped — no padding copy of a state-sized plane
+is made.  Per-group values (scales, change counts) travel as lane-dense
+(nb, 1, bg) rows, one per block: a rank-1 (bg,) block would need bg to be
+a multiple of 128 and still clash with XLA's T(1024) layout of 1-D
+arrays.  The wrappers flatten those rows back to (ng,).
+
+  new, base  (N,)        viewed as (ng, G); block (bg, G)
   q          (N,) int8   block (bg, G)          [int8 encode]
-  scale      (N/G,) f32  block (bg,)            [int8 encode]
+  scale      (ng,) f32   block (1, bg)          [int8 encode / decode]
   delta      (N,) f32    block (bg, G)          [lossless encode]
   resid      (N,) u32    block (bg, G)          [lossless encode]
-  group_changed (N/G,) i32  block (bg,)         [flat encoders]
-  group_rnnz    (N/G,) i32  block (bg,)         [flat lossless]
+  group_changed (ng,) i32  block (1, bg)        [flat encoders]
+  group_rnnz    (ng,) i32  block (1, bg)        [flat lossless]
 
-VMEM per step: 3-4 * bg * G fp32 (8 x 1024 -> 96-128 KB).
+VMEM per step: 3-4 * bg * G fp32 (64 x 1024 -> 0.75-1 MiB), double
+buffered.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 GROUP = 1024
+# rows per kernel block: 64 groups x 4 f32 planes = 1 MiB of VMEM.  Small
+# blocks make long grids, and Mosaic's compile time grows with the grid
+BLOCK_GROUPS = 64
+
+
+def _row(v: jax.Array) -> jax.Array:
+    """Per-group values (bg,) -> the lane-dense (1, bg) stats row."""
+    return v.reshape(1, v.shape[0])
+
+
+def _col(s: jax.Array) -> jax.Array:
+    """The (1, bg) stats row -> a (bg, 1) column that broadcasts over the
+    GROUP lanes of each row."""
+    return s.reshape(s.shape[1], 1)
+
+
+def _quantize(d: jax.Array):
+    scale = jnp.maximum(jnp.max(jnp.abs(d), axis=1), 1e-12) / 127.0
+    q = jnp.clip(jnp.round(d / scale[:, None]), -127, 127)
+    return q.astype(jnp.int8), scale
+
+
+def _bits(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+
+def _count(mask: jax.Array) -> jax.Array:
+    return _row(jnp.sum(mask.astype(jnp.int32), axis=1))
 
 
 def _encode_kernel(new_ref, base_ref, q_ref, s_ref):
-    d = new_ref[...].astype(jnp.float32) - base_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(d), axis=1)                    # (bg,)
-    scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(d / scale[:, None]), -127, 127)
-    q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
+    q, scale = _quantize(new_ref[...] - base_ref[...])
+    q_ref[...] = q
+    s_ref[...] = _row(scale)
 
 
 def _decode_kernel(q_ref, s_ref, d_ref):
-    d_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
-
-
-def _pad_to_groups(x: jax.Array) -> tuple[jax.Array, int]:
-    n = x.shape[0]
-    pad = (-n) % GROUP
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
-    return x, n
-
-
-def delta_encode_fwd(new: jax.Array, base: jax.Array, *, block_groups: int = 8,
-                     interpret: bool = False):
-    new, n = _pad_to_groups(new.reshape(-1))
-    base, _ = _pad_to_groups(base.reshape(-1))
-    ng = new.shape[0] // GROUP
-    bg = _grid_block(ng, block_groups)
-    new2 = new.reshape(ng, GROUP)
-    base2 = base.reshape(ng, GROUP)
-    q, s = pl.pallas_call(
-        _encode_kernel,
-        grid=(ng // bg,),
-        in_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg, GROUP), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                   pl.BlockSpec((bg,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((ng, GROUP), jnp.int8),
-                   jax.ShapeDtypeStruct((ng,), jnp.float32)],
-        interpret=interpret,
-    )(new2, base2)
-    del n
-    return q.reshape(-1), s   # padded to a multiple of GROUP; decode+slice
+    d_ref[...] = q_ref[...].astype(jnp.float32) * _col(s_ref[...])
 
 
 def _lossless_encode_kernel(new_ref, base_ref, d_ref, r_ref):
@@ -108,76 +112,13 @@ def _lossless_encode_kernel(new_ref, base_ref, d_ref, r_ref):
     d = new - base
     pred = base + d          # what decode will reconstruct, same rounding
     d_ref[...] = d
-    r_ref[...] = (jax.lax.bitcast_convert_type(new, jnp.uint32)
-                  ^ jax.lax.bitcast_convert_type(pred, jnp.uint32))
+    r_ref[...] = _bits(new) ^ _bits(pred)
 
 
 def _lossless_decode_kernel(base_ref, d_ref, r_ref, out_ref):
-    pred = base_ref[...] + d_ref[...]
-    bits = jax.lax.bitcast_convert_type(pred, jnp.uint32) ^ r_ref[...]
+    bits = _bits(base_ref[...] + d_ref[...]) ^ r_ref[...]
     out_ref[...] = jax.lax.bitcast_convert_type(bits, jnp.float32)
 
-
-def _grid_block(ng: int, block_groups: int) -> int:
-    bg = min(block_groups, ng)
-    while ng % bg != 0:
-        bg -= 1
-    return bg
-
-
-def lossless_encode_fwd(new: jax.Array, base: jax.Array, *,
-                        block_groups: int = 8, interpret: bool = False):
-    """Fused lossless encode: (f32 delta, u32 XOR residual), padded to a
-    multiple of GROUP (zero padding encodes to zero delta + zero residual,
-    so the padding compresses away)."""
-    new, n = _pad_to_groups(new.reshape(-1).astype(jnp.float32))
-    base, _ = _pad_to_groups(base.reshape(-1).astype(jnp.float32))
-    ng = new.shape[0] // GROUP
-    bg = _grid_block(ng, block_groups)
-    d, r = pl.pallas_call(
-        _lossless_encode_kernel,
-        grid=(ng // bg,),
-        in_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg, GROUP), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                   pl.BlockSpec((bg, GROUP), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((ng, GROUP), jnp.float32),
-                   jax.ShapeDtypeStruct((ng, GROUP), jnp.uint32)],
-        interpret=interpret,
-    )(new.reshape(ng, GROUP), base.reshape(ng, GROUP))
-    del n
-    return d.reshape(-1), r.reshape(-1)
-
-
-def lossless_decode_fwd(base: jax.Array, delta: jax.Array, resid: jax.Array,
-                        *, block_groups: int = 8,
-                        interpret: bool = False) -> jax.Array:
-    """Exact inverse of ``lossless_encode_fwd`` (returns the original f32
-    bit patterns; caller slices to the unpadded leaf size)."""
-    base, n = _pad_to_groups(base.reshape(-1).astype(jnp.float32))
-    delta, _ = _pad_to_groups(delta.reshape(-1).astype(jnp.float32))
-    resid, _ = _pad_to_groups(resid.reshape(-1).astype(jnp.uint32))
-    ng = base.shape[0] // GROUP
-    bg = _grid_block(ng, block_groups)
-    out = pl.pallas_call(
-        _lossless_decode_kernel,
-        grid=(ng // bg,),
-        in_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg, GROUP), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ng, GROUP), jnp.float32),
-        interpret=interpret,
-    )(base.reshape(ng, GROUP), delta.reshape(ng, GROUP),
-      resid.reshape(ng, GROUP))
-    del n
-    return out.reshape(-1)
-
-
-# ---------------------------------------------------------------------------
-# Flat (mega-buffer) encoders: one pallas_call over the packed f32 subtree,
-# with per-group change statistics fused into the same pass
-# ---------------------------------------------------------------------------
 
 def _flat_lossless_encode_kernel(new_ref, base_ref, d_ref, r_ref,
                                  c_ref, n_ref):
@@ -185,98 +126,173 @@ def _flat_lossless_encode_kernel(new_ref, base_ref, d_ref, r_ref,
     base = base_ref[...]
     d = new - base
     pred = base + d          # what decode will reconstruct, same rounding
-    r = (jax.lax.bitcast_convert_type(new, jnp.uint32)
-         ^ jax.lax.bitcast_convert_type(pred, jnp.uint32))
+    r = _bits(new) ^ _bits(pred)
     d_ref[...] = d
     r_ref[...] = r
-    changed = (jax.lax.bitcast_convert_type(new, jnp.uint32)
-               != jax.lax.bitcast_convert_type(base, jnp.uint32))
-    c_ref[...] = jnp.sum(changed.astype(jnp.int32), axis=1)
-    n_ref[...] = jnp.sum((r != 0).astype(jnp.int32), axis=1)
-
-
-def flat_lossless_encode_fwd(new: jax.Array, base: jax.Array, *,
-                             block_groups: int = 8, interpret: bool = False):
-    """One fused pass over the packed flat buffer (length a multiple of
-    GROUP — ``pipeline.FlatLayout`` guarantees the alignment): returns
-    (delta f32, resid u32, group_changed i32, group_rnnz i32)."""
-    n = new.reshape(-1).shape[0]
-    assert n % GROUP == 0, f"flat buffer length {n} not GROUP-aligned"
-    ng = n // GROUP
-    bg = _grid_block(ng, block_groups)
-    d, r, c, z = pl.pallas_call(
-        _flat_lossless_encode_kernel,
-        grid=(ng // bg,),
-        in_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg, GROUP), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                   pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                   pl.BlockSpec((bg,), lambda i: (i,)),
-                   pl.BlockSpec((bg,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((ng, GROUP), jnp.float32),
-                   jax.ShapeDtypeStruct((ng, GROUP), jnp.uint32),
-                   jax.ShapeDtypeStruct((ng,), jnp.int32),
-                   jax.ShapeDtypeStruct((ng,), jnp.int32)],
-        interpret=interpret,
-    )(new.reshape(ng, GROUP).astype(jnp.float32),
-      base.reshape(ng, GROUP).astype(jnp.float32))
-    return d.reshape(-1), r.reshape(-1), c, z
+    c_ref[...] = _count(_bits(new) != _bits(base))
+    n_ref[...] = _count(r != 0)
 
 
 def _flat_encode_kernel(new_ref, base_ref, q_ref, s_ref, c_ref):
-    new = new_ref[...].astype(jnp.float32)
-    base = base_ref[...].astype(jnp.float32)
-    d = new - base
-    amax = jnp.max(jnp.abs(d), axis=1)                    # (bg,)
-    scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(d / scale[:, None]), -127, 127)
-    q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
-    changed = (jax.lax.bitcast_convert_type(new, jnp.uint32)
-               != jax.lax.bitcast_convert_type(base, jnp.uint32))
-    c_ref[...] = jnp.sum(changed.astype(jnp.int32), axis=1)
+    new = new_ref[...]
+    base = base_ref[...]
+    q, scale = _quantize(new - base)
+    q_ref[...] = q
+    s_ref[...] = _row(scale)
+    c_ref[...] = _count(_bits(new) != _bits(base))
+
+
+ROWS, STAT = "rows", "stat"
+
+
+def _codec_call(kernel, planes, out_dtypes, *, stat_in=None,
+                block_groups: int, interpret: bool):
+    """Run ``kernel`` over (ng, GROUP) ``planes`` in blocks of ``bg`` rows.
+
+    ``out_dtypes`` is a list of (kind, dtype): ROWS outputs are (ng, GROUP)
+    planes, STAT outputs one value per group, returned as (ng,).
+    ``stat_in`` is an optional (ng,) per-group input (the int8 scales)."""
+    ng = planes[0].shape[0]
+    bg = ng if ng <= block_groups else block_groups
+    assert bg == ng or bg % 8 == 0, (ng, bg)
+    nb = pl.cdiv(ng, bg)
+    rows = pl.BlockSpec((bg, GROUP), lambda i: (i, 0))
+    stat = pl.BlockSpec((None, 1, bg), lambda i: (i, 0, 0))
+    args = list(planes)
+    in_specs = [rows] * len(planes)
+    if stat_in is not None:
+        stat_in = jnp.pad(stat_in, (0, nb * bg - ng)).reshape(nb, 1, bg)
+        args.append(stat_in)
+        in_specs.append(stat)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=in_specs,
+        out_specs=[rows if kind == ROWS else stat for kind, _ in out_dtypes],
+        out_shape=[jax.ShapeDtypeStruct((ng, GROUP) if kind == ROWS
+                                        else (nb, 1, bg), dt)
+                   for kind, dt in out_dtypes],
+        interpret=interpret,
+    )(*args)
+    return [o if kind == ROWS else o.reshape(-1)[:ng]
+            for o, (kind, _) in zip(outs, out_dtypes)]
+
+
+def _is_rows(x: jax.Array) -> bool:
+    return x.ndim == 2 and x.shape[1] == GROUP
+
+
+def _like(out: jax.Array, x: jax.Array) -> jax.Array:
+    """A ROWS output in the form of the input ``x``: (ng, GROUP) rows stay
+    rows, anything else gets the flat (GROUP-padded) vector."""
+    return out if _is_rows(x) else out.reshape(-1)
+
+
+def _as_rows(x: jax.Array, dtype) -> jax.Array:
+    """``x`` as whole GROUP rows, (ng, GROUP) of ``dtype``.  A plane that
+    already has that shape is used as it is; anything else is flattened
+    and zero-padded.  On TPU a 1-D array and its (ng, GROUP) view tile
+    differently, so that reshape is a copy of the whole plane: callers on
+    the hot path keep their planes as rows."""
+    if _is_rows(x):
+        return x.astype(dtype)
+    v = x.reshape(-1).astype(dtype)
+    pad = (-v.shape[0]) % GROUP
+    if pad:
+        v = jnp.concatenate([v, jnp.zeros((pad,), dtype)])
+    return v.reshape(-1, GROUP)
+
+
+def _flat_rows(x: jax.Array) -> jax.Array:
+    assert x.size % GROUP == 0, f"flat buffer of {x.size} not GROUP-aligned"
+    return _as_rows(x, jnp.float32)
+
+
+def delta_encode_fwd(new: jax.Array, base: jax.Array, *,
+                     block_groups: int = BLOCK_GROUPS,
+                     interpret: bool = False):
+    """(new - base) -> (int8 payload padded to a multiple of GROUP, in the
+    form of ``new``, per-group f32 scales); decode and slice to the leaf
+    size."""
+    q, s = _codec_call(
+        _encode_kernel,
+        [_as_rows(new, jnp.float32), _as_rows(base, jnp.float32)],
+        [(ROWS, jnp.int8), (STAT, jnp.float32)],
+        block_groups=block_groups, interpret=interpret)
+    return _like(q, new), s
+
+
+def lossless_encode_fwd(new: jax.Array, base: jax.Array, *,
+                        block_groups: int = BLOCK_GROUPS,
+                        interpret: bool = False):
+    """Fused lossless encode: (f32 delta, u32 XOR residual), padded to a
+    multiple of GROUP (zero padding encodes to zero delta + zero residual,
+    so the padding compresses away)."""
+    d, r = _codec_call(
+        _lossless_encode_kernel,
+        [_as_rows(new, jnp.float32), _as_rows(base, jnp.float32)],
+        [(ROWS, jnp.float32), (ROWS, jnp.uint32)],
+        block_groups=block_groups, interpret=interpret)
+    return _like(d, new), _like(r, new)
+
+
+def lossless_decode_fwd(base: jax.Array, delta: jax.Array, resid: jax.Array,
+                        *, block_groups: int = BLOCK_GROUPS,
+                        interpret: bool = False) -> jax.Array:
+    """Exact inverse of ``lossless_encode_fwd`` (returns the original f32
+    bit patterns, in the form of ``base``; the caller slices to the
+    unpadded leaf size)."""
+    (out,) = _codec_call(
+        _lossless_decode_kernel,
+        [_as_rows(base, jnp.float32), _as_rows(delta, jnp.float32),
+         _as_rows(resid, jnp.uint32)],
+        [(ROWS, jnp.float32)],
+        block_groups=block_groups, interpret=interpret)
+    return _like(out, base)
+
+
+def delta_decode_fwd(q: jax.Array, scales: jax.Array, *,
+                     block_groups: int = BLOCK_GROUPS,
+                     interpret: bool = False) -> jax.Array:
+    """Inverse of ``delta_encode_fwd``: the f32 delta, in the form of
+    ``q`` (the caller slices to the leaf size)."""
+    (d,) = _codec_call(
+        _decode_kernel, [_as_rows(q, jnp.int8)], [(ROWS, jnp.float32)],
+        stat_in=scales.reshape(-1).astype(jnp.float32),
+        block_groups=block_groups, interpret=interpret)
+    return _like(d, q)
+
+
+# ---------------------------------------------------------------------------
+# Flat (mega-buffer) encoders: one pallas_call over the packed f32 subtree,
+# with per-group change statistics fused into the same pass
+# ---------------------------------------------------------------------------
+
+def flat_lossless_encode_fwd(new: jax.Array, base: jax.Array, *,
+                             block_groups: int = BLOCK_GROUPS,
+                             interpret: bool = False):
+    """One fused pass over the packed flat buffer ((ng, GROUP) rows, or a
+    length that ``pipeline.FlatLayout`` keeps a multiple of GROUP):
+    returns (delta f32, resid u32) in the form of ``new`` and the
+    per-group (group_changed i32, group_rnnz i32)."""
+    d, r, c, z = _codec_call(
+        _flat_lossless_encode_kernel, [_flat_rows(new), _flat_rows(base)],
+        [(ROWS, jnp.float32), (ROWS, jnp.uint32), (STAT, jnp.int32),
+         (STAT, jnp.int32)],
+        block_groups=block_groups, interpret=interpret)
+    return _like(d, new), _like(r, new), c, z
 
 
 def flat_delta_encode_fwd(new: jax.Array, base: jax.Array, *,
-                          block_groups: int = 8, interpret: bool = False):
+                          block_groups: int = BLOCK_GROUPS,
+                          interpret: bool = False):
     """One fused int8 pass over the packed flat buffer: returns
-    (q int8, per-group f32 scales, group_changed i32).  Group alignment
+    (q int8 in the form of ``new``, per-group f32 scales, group_changed
+    i32).  Group alignment
     means every 1024-group quantizes elements of exactly one leaf, so the
     payload is numerically identical to the per-leaf encoder's."""
-    n = new.reshape(-1).shape[0]
-    assert n % GROUP == 0, f"flat buffer length {n} not GROUP-aligned"
-    ng = n // GROUP
-    bg = _grid_block(ng, block_groups)
-    q, s, c = pl.pallas_call(
-        _flat_encode_kernel,
-        grid=(ng // bg,),
-        in_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg, GROUP), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                   pl.BlockSpec((bg,), lambda i: (i,)),
-                   pl.BlockSpec((bg,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((ng, GROUP), jnp.int8),
-                   jax.ShapeDtypeStruct((ng,), jnp.float32),
-                   jax.ShapeDtypeStruct((ng,), jnp.int32)],
-        interpret=interpret,
-    )(new.reshape(ng, GROUP).astype(jnp.float32),
-      base.reshape(ng, GROUP).astype(jnp.float32))
-    return q.reshape(-1), s, c
-
-
-def delta_decode_fwd(q: jax.Array, scales: jax.Array, *, block_groups: int = 8,
-                     interpret: bool = False) -> jax.Array:
-    qp, n = _pad_to_groups(q.reshape(-1))
-    ng = qp.shape[0] // GROUP
-    bg = _grid_block(ng, block_groups)
-    d = pl.pallas_call(
-        _decode_kernel,
-        grid=(ng // bg,),
-        in_specs=[pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-                  pl.BlockSpec((bg,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((bg, GROUP), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ng, GROUP), jnp.float32),
-        interpret=interpret,
-    )(qp.reshape(ng, GROUP), scales)
-    del n
-    return d.reshape(-1)   # padded length; caller slices to the leaf size
+    q, s, c = _codec_call(
+        _flat_encode_kernel, [_flat_rows(new), _flat_rows(base)],
+        [(ROWS, jnp.int8), (STAT, jnp.float32), (STAT, jnp.int32)],
+        block_groups=block_groups, interpret=interpret)
+    return _like(q, new), s, c

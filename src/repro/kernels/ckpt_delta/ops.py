@@ -28,7 +28,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ckpt_delta.kernel import (GROUP, delta_decode_fwd,
+from repro.kernels.ckpt_delta.kernel import (BLOCK_GROUPS, GROUP,
+                                             delta_decode_fwd,
                                              delta_encode_fwd,
                                              flat_delta_encode_fwd,
                                              flat_lossless_encode_fwd,
@@ -43,21 +44,23 @@ def default_interpret() -> bool:
 
 
 @partial(jax.jit, static_argnames=("block_groups", "interpret"))
-def delta_encode(new, base, *, block_groups: int = 8, interpret: bool = False):
+def delta_encode(new, base, *, block_groups: int = BLOCK_GROUPS,
+                 interpret: bool = False):
     """(new - base) -> (int8 payload, per-1024-group fp32 scales)."""
     return delta_encode_fwd(new, base, block_groups=block_groups,
                             interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block_groups", "interpret"))
-def delta_decode(q, scales, *, block_groups: int = 8, interpret: bool = False):
+def delta_decode(q, scales, *, block_groups: int = BLOCK_GROUPS,
+                 interpret: bool = False):
     """Inverse of delta_encode (returns fp32 delta)."""
     return delta_decode_fwd(q, scales, block_groups=block_groups,
                             interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block_groups", "interpret"))
-def lossless_encode(new, base, *, block_groups: int = 8,
+def lossless_encode(new, base, *, block_groups: int = BLOCK_GROUPS,
                     interpret: bool = False):
     """Fused lossless encode: (f32 delta, u32 XOR residual) vs base."""
     return lossless_encode_fwd(new, base, block_groups=block_groups,
@@ -65,7 +68,7 @@ def lossless_encode(new, base, *, block_groups: int = 8,
 
 
 @partial(jax.jit, static_argnames=("block_groups", "interpret"))
-def lossless_decode(base, delta, resid, *, block_groups: int = 8,
+def lossless_decode(base, delta, resid, *, block_groups: int = BLOCK_GROUPS,
                     interpret: bool = False):
     """Bit-exact inverse of lossless_encode (returns the original f32)."""
     return lossless_decode_fwd(base, delta, resid, block_groups=block_groups,
@@ -84,7 +87,7 @@ def _bits_changed(new_f32: jax.Array, base_f32: jax.Array) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("block_groups", "interpret"))
-def lossless_encode_leaf(new, base, *, block_groups: int = 8,
+def lossless_encode_leaf(new, base, *, block_groups: int = BLOCK_GROUPS,
                          interpret: bool = False):
     """One leaf's on-device lossless encode: (delta f32, resid u32 — both
     GROUP-padded), plus ``changed`` (any bit differs -> leaf must be
@@ -101,7 +104,7 @@ def lossless_encode_leaf(new, base, *, block_groups: int = 8,
 
 
 @partial(jax.jit, static_argnames=("block_groups", "interpret"))
-def int8_encode_leaf(new, base, *, block_groups: int = 8,
+def int8_encode_leaf(new, base, *, block_groups: int = BLOCK_GROUPS,
                      interpret: bool = False):
     """One leaf's on-device int8 group-quantized delta encode: (q int8
     GROUP-padded, per-group f32 scales, changed).  Worst-case error per
@@ -121,80 +124,72 @@ def int8_encode_leaf(new, base, *, block_groups: int = 8,
 
 @jax.jit
 def pack_flat(leaves):
-    """Pack a sequence of f32 leaves into ONE flat device buffer, each
-    leaf zero-padded to a whole number of GROUPs so it starts at a
-    GROUP-aligned offset (``pipeline.FlatLayout`` records the offsets).
-    One jitted dispatch for the whole subtree; jit retraces per distinct
-    layout (leaf shape set), which the device base caches across
-    triggers."""
-    parts = []
-    for leaf in leaves:
+    """Pack a sequence of f32 leaves into ONE flat device buffer of
+    (ng, GROUP) rows, each leaf zero-padded to a whole number of GROUPs
+    so it starts at a GROUP-aligned offset (``pipeline.FlatLayout``
+    records the offsets).  The buffer is built as rows because on TPU a
+    1-D array and its (ng, GROUP) view tile differently: the encoders
+    then read it without a relayout copy.  Each leaf is written into a
+    zeroed buffer in place (a concatenate of the per-leaf rows would
+    first materialize every relayouted leaf: another state-sized
+    buffer).  One dispatch for the whole subtree; the checkpoint
+    pipeline compiles it ahead of time per layout (leaf shape set)."""
+    rows = [-(-leaf.size // GROUP) for leaf in leaves]
+    out = jnp.zeros((sum(rows), GROUP), jnp.float32)
+    at = 0
+    for leaf, n in zip(leaves, rows):
         v = leaf.reshape(-1).astype(jnp.float32)
-        pad = (-v.shape[0]) % GROUP
-        if pad:
-            v = jnp.concatenate([v, jnp.zeros((pad,), jnp.float32)])
-        parts.append(v)
-    return jnp.concatenate(parts)
+        v = jnp.pad(v, (0, n * GROUP - v.shape[0])).reshape(n, GROUP)
+        out = jax.lax.dynamic_update_slice(out, v, (at, 0))
+        at += n
+    return out
 
 
-def _flat_blocks(new_flat, base_flat, group_leaf, block_groups: int,
-                 interpret: bool):
-    """Pick the effective block size and zero-pad the flat pair to a whole
-    number of kernel BLOCKS (= bg groups), so ``_grid_block`` never has to
-    shrink the block to divide an awkward group count.  Interpret mode
+def _flat_block_groups(n: int, block_groups: int, interpret: bool) -> int:
+    """Kernel block size for an ``n``-element flat buffer.  Interpret mode
     (CPU backend) pays per grid STEP — each step re-slices the full
     operands — so there the whole buffer becomes ONE block (no VMEM bound
-    applies off-accelerator); compiled mode keeps ``block_groups`` (64
-    groups x 4 f32 planes = 1 MiB of VMEM).  Pad groups diff zero-vs-zero
-    (changed == rnnz == 0) and scatter onto leaf 0, adding nothing;
-    callers slice payloads back to ``n``."""
-    n = new_flat.shape[0]
-    if interpret:
-        block_groups = max(1, n // GROUP)
-    pad_g = (-(n // GROUP)) % block_groups
-    if pad_g:
-        z = jnp.zeros((pad_g * GROUP,), jnp.float32)
-        new_flat = jnp.concatenate([new_flat, z])
-        base_flat = jnp.concatenate([base_flat, z])
-        group_leaf = jnp.concatenate(
-            [group_leaf, jnp.zeros((pad_g,), group_leaf.dtype)])
-    return new_flat, base_flat, group_leaf, n, block_groups
+    applies off-accelerator); compiled mode keeps ``block_groups`` and
+    lets the final block run ragged."""
+    return max(1, n // GROUP) if interpret else block_groups
 
 
 @partial(jax.jit, static_argnames=("num_leaves", "block_groups", "interpret"))
 def flat_lossless_encode(new_flat, base_flat, group_leaf, *, num_leaves: int,
-                         block_groups: int = 64, interpret: bool = False):
-    """Fused lossless encode of the packed mega-buffer: ONE pallas_call
-    emits (delta f32, resid u32) plus per-group change stats, and a
+                         block_groups: int = BLOCK_GROUPS,
+                         interpret: bool = False):
+    """Fused lossless encode of the packed mega-buffer (``pack_flat``'s
+    rows): ONE pallas_call emits (delta f32, resid u32), in the form of
+    ``new_flat``, plus per-group change stats, and a
     scatter-add over ``group_leaf`` (the layout's group->leaf index map)
     reduces them to per-LEAF counts — returns (delta, resid,
     leaf_changed i32[num_leaves], leaf_rnnz i32[num_leaves]).  A leaf
     with ``leaf_changed == 0`` is bit-identical to the base (the skip-zero
     manifest marker); ``leaf_rnnz.sum() == 0`` means the residual plane is
     all-zero and its D2H can be skipped entirely."""
-    new_flat, base_flat, group_leaf, n, block_groups = _flat_blocks(
-        new_flat, base_flat, group_leaf, block_groups, interpret)
-    d, r, gc, gz = flat_lossless_encode_fwd(new_flat, base_flat,
-                                            block_groups=block_groups,
-                                            interpret=interpret)
+    d, r, gc, gz = flat_lossless_encode_fwd(
+        new_flat, base_flat, interpret=interpret,
+        block_groups=_flat_block_groups(new_flat.size, block_groups,
+                                        interpret))
     leaf_changed = jnp.zeros((num_leaves,), jnp.int32).at[group_leaf].add(gc)
     leaf_rnnz = jnp.zeros((num_leaves,), jnp.int32).at[group_leaf].add(gz)
-    return d[:n], r[:n], leaf_changed, leaf_rnnz
+    return d, r, leaf_changed, leaf_rnnz
 
 
 @partial(jax.jit, static_argnames=("num_leaves", "block_groups", "interpret"))
 def flat_int8_encode(new_flat, base_flat, group_leaf, *, num_leaves: int,
-                     block_groups: int = 64, interpret: bool = False):
+                     block_groups: int = BLOCK_GROUPS,
+                     interpret: bool = False):
     """Fused int8 encode of the packed mega-buffer: ONE pallas_call emits
-    (q int8, per-1024-group f32 scales) plus per-group change counts,
+    (q int8 in the form of ``new_flat``, per-1024-group f32 scales) plus
+    per-group change counts,
     reduced to per-leaf via scatter-add — returns (q, scales,
     leaf_changed i32[num_leaves]).  Group alignment keeps every scale
     group within a single leaf, so the payload matches the per-leaf
     encoder's bit for bit."""
-    new_flat, base_flat, group_leaf, n, block_groups = _flat_blocks(
-        new_flat, base_flat, group_leaf, block_groups, interpret)
-    q, s, gc = flat_delta_encode_fwd(new_flat, base_flat,
-                                     block_groups=block_groups,
-                                     interpret=interpret)
+    q, s, gc = flat_delta_encode_fwd(
+        new_flat, base_flat, interpret=interpret,
+        block_groups=_flat_block_groups(new_flat.size, block_groups,
+                                        interpret))
     leaf_changed = jnp.zeros((num_leaves,), jnp.int32).at[group_leaf].add(gc)
-    return q[:n], s[:n // GROUP], leaf_changed
+    return q, s, leaf_changed

@@ -208,7 +208,11 @@ def flash_attention_xla(q, k, v, *, causal: bool, window: int = 0,
             s = jnp.einsum("bqnh,bsnh->bnqs", qc, kc).astype(jnp.float32) * scale
             s = _softcap(s, softcap)
             s = s + _mask_bias(q_pos, k_pos, causal, window)
-            m_new = jnp.maximum(m, s.max(-1))
+            # the running max only keeps exp in range: the output does not
+            # depend on it, so no gradient flows through it.  Differentiating
+            # max() ties gradients to equality with the recomputed scores,
+            # which a fused TPU recomputation can miss (0/0: NaN grads).
+            m_new = jax.lax.stop_gradient(jnp.maximum(m, s.max(-1)))
             corr = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
             l_new = l * corr + p.sum(-1)

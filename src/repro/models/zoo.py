@@ -614,12 +614,17 @@ def cache_specs(cfg: ModelConfig, shape: ShapeConfig) -> Any:
     raise ValueError(cfg.family)
 
 
+def init_state(cfg: ModelConfig, optimizer: Optimizer, rng: jax.Array) -> dict:
+    """TrainState {params, opt, step} with freshly initialized params."""
+    params = init_params(cfg, rng)
+    return {"params": params, "opt": optimizer.init(params),
+            "step": jnp.zeros((), jnp.int32)}
+
+
 def state_specs(cfg: ModelConfig, optimizer: Optimizer) -> dict:
     """TrainState ShapeDtypeStructs via eval_shape (no allocation)."""
-    params = jax.eval_shape(partial(init_params, cfg), jax.random.PRNGKey(0))
-    opt = jax.eval_shape(optimizer.init, params)
-    return {"params": params, "opt": opt,
-            "step": _sds((), "int32")}
+    return jax.eval_shape(partial(init_state, cfg, optimizer),
+                          jax.random.PRNGKey(0))
 
 
 def build_model(cfg: ModelConfig):

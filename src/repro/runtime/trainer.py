@@ -42,6 +42,7 @@ from repro.ft.failures import Degradation, InjectedFailure, jitter_phase
 from repro.metrics import MetricsStore
 from repro.models import zoo
 from repro.optim import make_optimizer
+from repro.utils.trees import place_like
 
 
 @dataclass
@@ -83,9 +84,8 @@ class ResilientTrainer:
         self.metrics = MetricsStore()
         self.step_fn = jax.jit(zoo.make_train_step(model_cfg, self.optimizer,
                                                    self.opt_cfg))
-        params = zoo.init_params(model_cfg, jax.random.PRNGKey(seed))
-        self.state = {"params": params, "opt": self.optimizer.init(params),
-                      "step": jnp.zeros((), jnp.int32)}
+        self.state = zoo.init_state(model_cfg, self.optimizer,
+                                    jax.random.PRNGKey(seed))
         # AOT-compile the step so jit compilation never counts as virtual
         # job time (the first step would otherwise eat the whole experiment)
         from repro.config import ShapeConfig
@@ -95,6 +95,7 @@ class ResilientTrainer:
         state_struct = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state)
         self.step_fn = self.step_fn.lower(state_struct, specs).compile()
+        self.ckpt.prepare(self.state)
         self.t = 0.0                       # virtual clock (seconds)
         self.failure_schedule: list[float] = []
         self.degradation_schedule: list[Degradation] = []
@@ -216,6 +217,7 @@ class ResilientTrainer:
         self.ckpt = CheckpointManager(self.tcfg.ckpt_dir, plan,
                                       policy=self.policy)
         self.ckpt.adopt_runtime_state(old)
+        self.ckpt.prepare(self.state)
         self.events.append({"t": self.t, "event": "set_plan",
                             "plan": plan.name, "ci": plan.interval_s})
 
@@ -241,7 +243,8 @@ class ResilientTrainer:
         except FileNotFoundError:
             self.events.append({"t": self.t, "event": "restore_fresh"})
             return
-        self.state = jax.tree_util.tree_map(jnp.asarray, report.state)
+        # each leaf goes back with the sharding the live state had
+        self.state = place_like(report.state, self.state)
         self.batcher.restore(report.extra["pipeline"])
         self.events.append({"t": self.t, "event": "restore",
                             "step": report.step, "level": report.level,

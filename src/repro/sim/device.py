@@ -19,10 +19,14 @@ The sim package is a three-engine hierarchy (ROADMAP item 4):
 
 Each engine is authoritative one level down: the scalar oracle defines
 WHAT a tick does, the NumPy engine defines the floating-point ORDER of
-the batched tick, and the device engine must reproduce that order
-bit-exactly (``tests/test_device_campaign.py`` asserts
+the batched tick, and on the CPU backend the device engine reproduces
+that order bit-exactly (``tests/test_device_campaign.py`` asserts
 ``assert_array_equal`` parity across plans, crash kinds, degradation
-kinds, and mid-run plan switches).  Use the scalar for semantics work,
+kinds, and mid-run plan switches).  The claim is for XLA:CPU only: a TPU
+has no native f64, XLA emulates it, and on a v5e chip all 60 lanes of
+that parity matrix diverge from NumPy while every lane stays finite
+(``chip_smoke.py``'s campaign phase prints the count).  Use the scalar
+for semantics work,
 the NumPy engine for moderate grids and as the parity reference, and the
 device engine for mega-campaigns (10^5+ lanes) and exhaustive plan
 sweeps (``optimize_plan(..., exhaustive=True, engine="device")``).
@@ -78,7 +82,6 @@ ensure_bitexact_cpu()
 import jax                                                          # noqa: E402
 import jax.numpy as jnp                                             # noqa: E402
 from jax import lax                                                 # noqa: E402
-from jax.experimental import enable_x64                             # noqa: E402
 
 
 def fma_contraction_active() -> bool:
@@ -87,7 +90,7 @@ def fma_contraction_active() -> bool:
     rng = np.random.default_rng(0)
     a = rng.random(256)
     b = rng.random(256)
-    with enable_x64():
+    with jax.enable_x64(True):
         jv = np.asarray(jax.jit(lambda x, y: 0.9 * x + 0.1 * y)(
             jnp.asarray(a), jnp.asarray(b)))
     return not np.array_equal(0.9 * a + 0.1 * b, jv)
@@ -477,7 +480,7 @@ class DeviceCampaign(BatchedCampaign):
         fn = _chunk_fn(self._hist_rows, self._any_deg, has_fail, lat_extra,
                        track_af)
         cost = self.cost
-        with enable_x64():
+        with jax.enable_x64(True):
             if self._rates_dev is None:
                 self._rates_dev = jnp.asarray(self._rates_u)
             const_names = _LANE_CONST + ro_consts

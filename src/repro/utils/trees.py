@@ -65,3 +65,11 @@ def tree_map_with_names(fn: Callable[[str, Any], Any], tree: Any) -> Any:
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: fn(_name_of_path(path), leaf), tree
     )
+
+
+def place_like(tree: Any, like: Any) -> Any:
+    """Put each leaf of the host ``tree`` on device with the sharding of
+    the matching leaf of ``like`` (a restored state takes the layout of
+    the live state it replaces, not the default device alone)."""
+    return jax.tree_util.tree_map(
+        lambda x, ref: jax.device_put(x, ref.sharding), tree, like)

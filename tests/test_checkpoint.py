@@ -33,6 +33,27 @@ def test_store_roundtrip(tmp_path):
     assert extra["cursor"] == 42
 
 
+@pytest.mark.parametrize("replicated", [False, True])
+def test_store_roundtrip_bf16_leaves_bitwise(tmp_path, replicated):
+    """bf16 leaves (bf16 Adam state) restore bit for bit through the plain
+    and the peer-replicated store: NumPy writes ml_dtypes to .npz as raw
+    void bytes, which restore must reinterpret, not cast."""
+    from repro.checkpoint import PeerReplicatedStore
+    rng = np.random.default_rng(3)
+    s = {"p": rng.standard_normal((64, 8)).astype(np.float32),
+         "m": jnp.asarray(rng.standard_normal((64, 8)), jnp.bfloat16),
+         "step": np.int32(2)}
+    store = (PeerReplicatedStore(str(tmp_path), num_shards=2,
+                                 replication_factor=1) if replicated
+             else CheckpointStore(str(tmp_path), num_shards=2))
+    store.save(2, s)
+    restored, _ = store.restore(s)
+    assert restored["m"].dtype == jnp.bfloat16
+    for name in s:
+        a, b = np.asarray(s[name]), np.asarray(restored[name])
+        assert a.tobytes() == b.tobytes(), name
+
+
 def test_store_reshard_restore_across_host_counts(tmp_path):
     """Save with 8 shards, restore through a store configured for 2 —
     manifest-driven restore is shard-count agnostic (elastic rescale)."""

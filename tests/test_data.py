@@ -2,11 +2,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # offline env: fixed-seed fallback below
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.data import (EventStream, StreamingBatcher, WorkloadRecording,
                         constant_rate, ctr_rate, diurnal_rate, record_workload)
@@ -93,13 +89,7 @@ def _check_event_tokens_deterministic(seed, offset):
     assert a.min() >= 0 and a.max() < 1000
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=20, deadline=None)
-    @given(seed=st.integers(0, 1000), offset=st.integers(0, 10_000))
-    def test_event_tokens_deterministic_by_offset(seed, offset):
-        _check_event_tokens_deterministic(seed, offset)
-else:
-    @pytest.mark.parametrize("seed,offset", [
-        (0, 0), (1, 1), (7, 123), (42, 4096), (999, 9_999), (1000, 10_000)])
-    def test_event_tokens_deterministic_by_offset(seed, offset):
-        _check_event_tokens_deterministic(seed, offset)
+@settings(max_examples=20, deadline=None)
+@given(seed=st.integers(0, 1000), offset=st.integers(0, 10_000))
+def test_event_tokens_deterministic_by_offset(seed, offset):
+    _check_event_tokens_deterministic(seed, offset)

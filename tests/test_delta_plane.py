@@ -150,43 +150,62 @@ def test_flat_ops_match_ref_on_awkward_leaf_sizes():
     nl = len(sizes)
     nf = pack_flat([jnp.asarray(x) for x in new])
     bf = pack_flat([jnp.asarray(x) for x in base])
-    assert np.array_equal(np.asarray(nf), pack_flat_ref(new))
+    assert nf.shape == (layout.total // GROUP, GROUP)   # packed as rows
+    assert np.array_equal(np.asarray(nf).reshape(-1), pack_flat_ref(new))
     gl = layout.group_leaf_device()
     d, r, lc, lz = flat_lossless_encode(nf, bf, gl, num_leaves=nl,
                                         interpret=True)
     d_ref, r_ref, lc_ref, lz_ref = flat_lossless_encode_ref(
         pack_flat_ref(new), pack_flat_ref(base), layout.group_leaf, nl)
-    assert np.array_equal(np.asarray(d), d_ref)
-    assert np.array_equal(np.asarray(r), r_ref)
+    assert d.shape == r.shape == nf.shape
+    assert np.array_equal(np.asarray(d).reshape(-1), d_ref)
+    assert np.array_equal(np.asarray(r).reshape(-1), r_ref)
     assert np.array_equal(np.asarray(lc), lc_ref)
     assert np.array_equal(np.asarray(lz), lz_ref)
     assert int(lc_ref[1]) == 0 and lc_ref[[0, 2, 3, 4]].all()
     q, s, lc2 = flat_int8_encode(nf, bf, gl, num_leaves=nl, interpret=True)
     q_ref, s_ref, _ = flat_int8_encode_ref(
         pack_flat_ref(new), pack_flat_ref(base), layout.group_leaf, nl)
-    assert np.array_equal(np.asarray(q), q_ref)
+    assert np.array_equal(np.asarray(q).reshape(-1), q_ref)
     assert np.array_equal(np.asarray(s), s_ref)
     assert np.array_equal(np.asarray(lc2), lc_ref)
 
 
 def test_flat_blocks_pads_to_block_multiple():
-    """Compiled-mode block padding: an 11-group buffer at block_groups=4
-    pads to 12 groups of zero-vs-zero diff scattered onto leaf 0 (adding
-    nothing); interpret mode collapses to ONE whole-buffer block,
-    unpadded — the per-grid-step cost structure documented on
-    ``_flat_blocks``."""
-    from repro.kernels.ckpt_delta.ops import _flat_blocks
+    """Compiled-mode blocking: an 11-group buffer at block_groups=8 runs a
+    ragged final block (3 of 8 rows in range) instead of padding the
+    state-sized planes to a block multiple; its out-of-range rows (the
+    interpreter fills them with NaN) must change neither the payload nor
+    the per-group statistics.  Interpret mode collapses to ONE
+    whole-buffer block — the per-grid-step cost structure documented on
+    ``_flat_block_groups``."""
+    from repro.kernels.ckpt_delta.kernel import (flat_delta_encode_fwd,
+                                                 flat_lossless_encode_fwd)
+    from repro.kernels.ckpt_delta.ops import _flat_block_groups
 
-    n = 11 * GROUP
-    nf = jnp.ones((n,), jnp.float32)
-    bf = jnp.zeros((n,), jnp.float32)
-    gl = jnp.asarray(np.arange(11, dtype=np.int32) // 3)
-    nf2, bf2, gl2, n2, bg = _flat_blocks(nf, bf, gl, 4, interpret=False)
-    assert (n2, bg) == (n, 4) and nf2.shape[0] == 12 * GROUP
-    assert not np.asarray(nf2[n:]).any() and not np.asarray(bf2[n:]).any()
-    assert int(gl2[-1]) == 0
-    nf3, _, gl3, n3, bg3 = _flat_blocks(nf, bf, gl, 4, interpret=True)
-    assert (n3, bg3) == (n, 11) and nf3.shape[0] == n and gl3.shape[0] == 11
+    rng = np.random.default_rng(11)
+    base = rng.standard_normal((11, GROUP)).astype(np.float32)
+    new = base + rng.uniform(-1e-2, 1e-2, base.shape).astype(np.float32)
+    new[4] = base[4]                             # one unchanged group
+    d, r, gc, gz = flat_lossless_encode_fwd(
+        jnp.asarray(new), jnp.asarray(base), block_groups=8, interpret=True)
+    d_ref, r_ref = lossless_encode_ref(new, base)
+    assert d.shape == (11, GROUP) and gc.shape == gz.shape == (11,)
+    assert np.array_equal(np.asarray(d).reshape(-1), d_ref)
+    assert np.array_equal(np.asarray(r).reshape(-1), r_ref)
+    changed = (new.view(np.uint32) != base.view(np.uint32)).sum(axis=1)
+    assert np.array_equal(np.asarray(gc), changed) and int(gc[4]) == 0
+    assert np.array_equal(np.asarray(gz),
+                          (r_ref.reshape(11, GROUP) != 0).sum(axis=1))
+    q, s, gc8 = flat_delta_encode_fwd(
+        jnp.asarray(new), jnp.asarray(base), block_groups=8, interpret=True)
+    q_ref, s_ref, _ = flat_int8_encode_ref(
+        new.reshape(-1), base.reshape(-1), np.zeros(11, np.int32), 1)
+    assert np.array_equal(np.asarray(q).reshape(-1), q_ref)
+    assert np.array_equal(np.asarray(s), s_ref)
+    assert np.array_equal(np.asarray(gc8), changed)
+    assert _flat_block_groups(11 * GROUP, 8, interpret=False) == 8
+    assert _flat_block_groups(11 * GROUP, 8, interpret=True) == 11
 
 
 # ---------------------------------------------------------------------------
@@ -416,6 +435,33 @@ def test_plan_switch_carries_device_base_over(tmp_path):
     assert mgr2._device_base is None
     rep2 = mgr2.save(3, s2, 3.0)
     assert rep2.kind == "full"          # chain restarts
+
+
+@pytest.mark.parametrize("codec", ["lossless", "int8"])
+def test_prepare_compiles_device_triggers_ahead(tmp_path, codec):
+    """After ``prepare`` the full trigger's pack and the delta trigger's
+    pack and encode find their executables compiled: no trigger compiles."""
+    from repro.checkpoint import pipeline
+    rng = np.random.default_rng(7)
+    # leaf sizes no other test packs, so the compile caches start cold
+    state = {"a": jnp.asarray(rng.standard_normal((3, 1111), np.float32)),
+             "b": jnp.asarray(rng.standard_normal((77,), np.float32)),
+             "step": jnp.asarray(np.int32(0))}
+    mgr = CheckpointManager(str(tmp_path), CheckpointPlan(
+        mode="incremental", full_every=4, encode_placement="device",
+        delta_codec=codec))
+    mgr.prepare(state)
+    misses = (pipeline._compiled_pack.cache_info().misses,
+              pipeline._compiled_encoder.cache_info().misses)
+    assert mgr.save(0, state, 0.0).kind == "full"
+    nxt = {**state, "a": state["a"] + np.float32(1e-3)}
+    assert mgr.save(1, nxt, 1.0).kind == "delta"
+    assert (pipeline._compiled_pack.cache_info().misses,
+            pipeline._compiled_encoder.cache_info().misses) == misses
+    got = mgr.restore(state, "node")
+    assert got.step == 1
+    if codec == "lossless":
+        assert _bit_exact(got.state, nxt)
 
 
 def test_save_report_bytes_on_link_distinguishes_link_from_disk(tmp_path):

@@ -6,6 +6,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -22,6 +23,21 @@ def _rules(arch="yi-6b", multi=False, **scfg):
         ("pod", "data", "model") if multi else ("data", "model"))
     return ShardingRules(get_config(arch), mesh,
                          ShardingConfig(**scfg))
+
+
+def test_meshes_have_auto_axes():
+    """The sharding rules pin layouts with with_sharding_constraint, which
+    takes only Auto axes; jax.make_mesh defaults to Explicit ones."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_host_mesh
+    host = make_host_mesh()
+    assert host.axis_names == ("data", "model")
+    assert host.devices.size == len(jax.devices())
+    for mesh in (host, _rules().mesh):
+        assert set(mesh.axis_types) == {AxisType.Auto}
+    with pytest.raises(ValueError):
+        make_host_mesh(model=len(jax.devices()) + 1)
 
 
 def test_tp_sharding_of_core_weights():
@@ -101,7 +117,8 @@ def test_real_compile_on_8_fake_devices():
                           num_heads=4, num_kv_heads=2, d_ff=256, vocab_size=512)
         shape = ShapeConfig("t", "train", 64, 8)
         opt_cfg = OptimizerConfig(); opt = make_optimizer(opt_cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh(4, 2)
         rules = ShardingRules(cfg, mesh, ShardingConfig(fsdp_min_params=0))
         ann = rules.annotator()
         state = zoo.state_specs(cfg, opt)
@@ -119,7 +136,8 @@ def test_real_compile_on_8_fake_devices():
     try:
         res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                              text=True, timeout=300,
-                             env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                             env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                                  "JAX_PLATFORMS": "cpu"})
     except (subprocess.TimeoutExpired, OSError) as e:
         pytest.skip(f"8-device compile subprocess did not finish here: {e!r:.200}")
     if res.returncode != 0 and ("ImportError" in res.stderr
@@ -129,3 +147,26 @@ def test_real_compile_on_8_fake_devices():
     assert res.returncode == 0, res.stderr[-2000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert out["ok"]
+
+
+def test_host_run_restores_into_the_step_layout(tmp_path):
+    """The launcher's host run: sharded steps, a full-sync checkpoint, a
+    node failure, and a restore whose leaves take the layout the compiled
+    step declares; the next step from it equals the live one's."""
+    from repro.config import ModelConfig
+    from repro.launch.train import host_run
+    cfg = ModelConfig(name="tiny", family="dense", num_layers=2, d_model=64,
+                      num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=256)
+    out = host_run(cfg, jax.devices(), str(tmp_path), batch=2, seq_len=32,
+                   steps=2)
+    assert np.isfinite(out["losses"]).all() and len(out["losses"]) == 2
+    assert out["save"].kind == "full" and out["save"].step == 2
+    assert out["restore_kind"] == "full" and out["restore_step"] == 2
+    assert out["bit_exact"]
+    declared = jax.tree_util.tree_leaves(out["step"].fn.input_shardings[0][0])
+    restored = jax.tree_util.tree_leaves(out["restored_shardings"])
+    specs = jax.tree_util.tree_leaves(out["step"].state_specs)
+    assert len(restored) == len(declared) == len(specs)
+    assert all(a.is_equivalent_to(d, s.ndim)
+               for a, d, s in zip(restored, declared, specs))
+    assert out["next_loss"] == out["next_loss_restored"]

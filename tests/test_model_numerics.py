@@ -31,6 +31,29 @@ def test_flash_xla_window_matches_full():
     np.testing.assert_allclose(np.asarray(fl), np.asarray(full), atol=2e-5)
 
 
+@pytest.mark.parametrize("window", [0, 48])
+def test_flash_xla_grads_match_full_attention(window):
+    """The chunked path's running max carries no gradient: d/dq, d/dk and
+    d/dv still equal full attention's, with a causal mask and a window."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    B, S, H, hd = 1, 256, 2, 32
+    q, k, v = (jax.random.normal(kk, (B, S, H, hd)) for kk in ks[:3])
+    cot = jax.random.normal(ks[3], (B, S, H, hd))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) * cot)
+
+    full = jax.grad(loss(lambda q, k, v: L.full_attention(
+        q, k, v, causal=True, window=window)), argnums=(0, 1, 2))(q, k, v)
+    fl = jax.grad(loss(lambda q, k, v: L.flash_attention_xla(
+        q, k, v, causal=True, window=window, chunk_q=64, chunk_kv=64)),
+        argnums=(0, 1, 2))(q, k, v)
+    for name, g_fl, g_full in zip("qkv", fl, full):
+        assert np.isfinite(np.asarray(g_fl)).all(), name
+        np.testing.assert_allclose(np.asarray(g_fl), np.asarray(g_full),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
 def test_rglru_sequence_matches_kernel_ref():
     """The model's rglru_sequence recurrence == the kernel oracle recurrence
     given identical gates."""

@@ -81,3 +81,27 @@ def test_young_daly_baseline_in_range(pipeline):
     _, _, cost, _, _, _, _ = pipeline
     w = young_daly_interval(cost.ckpt_duration_s, mtbf_s=3600.0)
     assert 60 < w < 240
+
+
+def test_compile_cache_dir_env_wins_else_checkout(monkeypatch):
+    """Entry points keep JAX's persistent compilation cache where
+    JAX_COMPILATION_CACHE_DIR says and set none of their own; unset, it
+    goes to the fixed .jax_cache/ at the checkout root."""
+    import os
+
+    import jax
+
+    from repro.utils.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert str(CHECKOUT_CACHE_DIR) == os.path.join(root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+

@@ -56,6 +56,7 @@ from repro.checkpoint.store import (CheckpointStore, compress_frames,
                                     get_compressor, get_decompressor,
                                     publish_dir_atomic, write_json_atomic)
 from repro.kernels.ckpt_delta.ref import GROUP
+from repro.utils.timing import span
 from repro.utils.trees import tree_flatten_with_names
 
 
@@ -183,9 +184,14 @@ def write_delta(directory: str, step: int, state_np: Any, base: Any,
             if isinstance(arr, str):    # "zero": residual D2H was skipped
                 flat_meta["arrays"][sfx] = "zero"
                 continue
-            frames, lens, cpu = compress_frames(arr, compress, io_pool())
+            with span("khaos.ckpt.compress", step=step,
+                      bytes_in=arr.nbytes) as sp:
+                frames, lens, cpu = compress_frames(arr, compress, io_pool())
+                sp.add(bytes_out=sum(lens))
             fname = f"flat@{sfx}.bin"
-            with open(os.path.join(tmp, fname), "wb") as f:
+            with span("khaos.ckpt.write", step=step,
+                      bytes_written=sum(lens)), \
+                    open(os.path.join(tmp, fname), "wb") as f:
                 for frame in frames:
                     f.write(frame)
             flat_meta["arrays"][sfx] = {"file": fname,

@@ -64,7 +64,8 @@ import jax
 import numpy as np
 
 from repro.checkpoint.async_ckpt import BackgroundCommitter
-from repro.checkpoint.incremental import (apply_delta, newest_delta_step,
+from repro.checkpoint.incremental import (apply_delta, delta_dir,
+                                          newest_delta_step,
                                           read_delta_manifest, write_delta)
 from repro.checkpoint.multilevel import allowed_levels
 from repro.checkpoint.pipeline import (ChunkedHostSnapshot, DeltaLeafSource,
@@ -74,6 +75,8 @@ from repro.checkpoint.policy import CheckpointPolicy
 from repro.checkpoint.replication import PeerReplicatedStore
 from repro.checkpoint.store import CheckpointStore
 from repro.config import CheckpointPlan
+from repro.utils.timing import span
+from repro.utils.trees import tree_bytes
 
 
 @dataclass
@@ -210,14 +213,21 @@ class CheckpointManager:
 
     def save(self, step: int, state: Any, timestamp: float = 0.0,
              extra: Optional[dict] = None) -> SaveReport:
-        extra = extra or {}
-        if self._committer is not None and self._committer.busy:
-            if self.plan.busy_policy == "skip":
-                self.skips += 1
-                self._count += 1          # the trigger happened; cadence moves on
-                self._mark_trigger(timestamp)
-                return SaveReport(step, "skipped", synchronous=False)
-            self._committer.wait()
+        with span("khaos.ckpt.save", step=step) as sp:
+            return self._save(sp.id, step, state, timestamp, extra or {})
+
+    def _save(self, save_id: int, step: int, state: Any, timestamp: float,
+              extra: dict) -> SaveReport:
+        if self._committer is not None:
+            with span("khaos.ckpt.commit_wait"):
+                if self._committer.busy:
+                    if self.plan.busy_policy == "skip":
+                        self.skips += 1
+                        # the trigger happened; cadence moves on
+                        self._count += 1
+                        self._mark_trigger(timestamp)
+                        return SaveReport(step, "skipped", synchronous=False)
+                    self._committer.wait()
 
         t0 = time.monotonic()
         kind = self._kind()
@@ -236,18 +246,22 @@ class CheckpointManager:
         device_delta = (kind == "delta"
                         and self.plan.encode_placement == "device"
                         and self._device_base is not None)
-        if device_delta:
-            # encode in front of D2H: only the encoded payload crosses the
-            # link; raw leaves stay lazily reachable (memory-level parking,
-            # delta-upgraded-to-full self-heal) through immutable refs
-            snap = DeltaLeafSource(state, self._device_base,
-                                   codec=self.plan.delta_codec,
-                                   chunk_bytes=self.plan.chunk_bytes)
-        else:
-            snap = (ChunkedHostSnapshot(
-                        state, self.plan.chunk_bytes,
-                        defer_device=not self.plan.eager_snapshot)
-                    if need_copy else PlainLeafSource(state))
+        with span("khaos.ckpt.snapshot"):
+            if device_delta:
+                # encode in front of D2H: only the encoded payload crosses
+                # the link; raw leaves stay lazily reachable (memory-level
+                # parking, delta-upgraded-to-full self-heal) through
+                # immutable refs
+                snap = DeltaLeafSource(state, self._device_base,
+                                       codec=self.plan.delta_codec,
+                                       chunk_bytes=self.plan.chunk_bytes)
+            else:
+                snap = (ChunkedHostSnapshot(
+                            state, self.plan.chunk_bytes,
+                            defer_device=not self.plan.eager_snapshot)
+                        if need_copy else PlainLeafSource(state))
+            if kind == "full" and self.plan.encode_placement == "device":
+                self._device_base = DeviceDeltaBase(state)
         if "memory" in levels:
             # the memory level always holds the decoded newest state (as a
             # possibly-still-transferring snapshot source) — a task restart
@@ -256,8 +270,6 @@ class CheckpointManager:
             self.saves_by_level["memory"] += 1
         if kind == "full":
             self._base, self._base_step = snap, step
-            if self.plan.encode_placement == "device":
-                self._device_base = DeviceDeltaBase(state)
         base, base_step = self._base, self._base_step
         self._count += 1
 
@@ -265,42 +277,45 @@ class CheckpointManager:
         report = SaveReport(step, kind, tuple(levels), synchronous=self._committer is None)
 
         def commit() -> None:
-            nbytes, paths, encode_s = 0, [], 0.0
-            for level in disk:
-                store = self.stores[level]
-                # remote only ever receives fulls; a delta whose base full
-                # is missing at a level would be unrestorable there
-                write_full = (kind == "full" or level == "remote"
-                              or store.newest() != base_step)
-                if write_full:
-                    paths.append(store.save(step, snap, timestamp,
-                                            {**extra, "kind": "full"}))
-                    n = store.total_bytes(step)
-                    nbytes += n
-                    self.bytes_by_kind["full"] += n
-                else:
-                    p, n, enc = write_delta(store.directory, step, snap,
-                                            base, base_step, timestamp,
-                                            extra,
-                                            self.plan.delta_codec,
-                                            self.plan.codec)
-                    paths.append(p)
-                    nbytes += n
-                    encode_s += enc
-                    self.bytes_by_kind["delta"] += n
-                    if isinstance(store, PeerReplicatedStore):
-                        # deltas aren't physically replicated (the post-
-                        # failure chain restarts from a full) but their
-                        # mirror traffic is priced — keep the measured
-                        # replica_bytes twin honest
-                        store.account_delta_mirror(n)
-                self.saves_by_level[level] += 1
-            report.bytes_written = nbytes
-            report.bytes_on_link = snap.bytes_on_link()
-            self.link_bytes += report.bytes_on_link
-            report.encode_s = encode_s
-            report.paths = tuple(paths)
-            report.duration_s = time.monotonic() - t0
+            with span("khaos.ckpt.commit", step=step,
+                      parent=save_id) as csp:
+                nbytes, paths, encode_s = 0, [], 0.0
+                for level in disk:
+                    store = self.stores[level]
+                    # remote only ever receives fulls; a delta whose base full
+                    # is missing at a level would be unrestorable there
+                    write_full = (kind == "full" or level == "remote"
+                                  or store.newest() != base_step)
+                    if write_full:
+                        paths.append(store.save(step, snap, timestamp,
+                                                {**extra, "kind": "full"}))
+                        n = store.total_bytes(step)
+                        nbytes += n
+                        self.bytes_by_kind["full"] += n
+                    else:
+                        p, n, enc = write_delta(store.directory, step, snap,
+                                                base, base_step, timestamp,
+                                                extra,
+                                                self.plan.delta_codec,
+                                                self.plan.codec)
+                        paths.append(p)
+                        nbytes += n
+                        encode_s += enc
+                        self.bytes_by_kind["delta"] += n
+                        if isinstance(store, PeerReplicatedStore):
+                            # deltas aren't physically replicated (the post-
+                            # failure chain restarts from a full) but their
+                            # mirror traffic is priced — keep the measured
+                            # replica_bytes twin honest
+                            store.account_delta_mirror(n)
+                    self.saves_by_level[level] += 1
+                csp.add(bytes_written=nbytes)
+                report.bytes_written = nbytes
+                report.bytes_on_link = snap.bytes_on_link()
+                self.link_bytes += report.bytes_on_link
+                report.encode_s = encode_s
+                report.paths = tuple(paths)
+                report.duration_s = time.monotonic() - t0
 
         if self._committer is None:
             commit()
@@ -322,30 +337,40 @@ class CheckpointManager:
         if the next action discards this manager (a plan switch rebuild).
         Does not advance the trigger count (cadence patterns are
         unaffected); does anchor a fresh delta chain at ``step``."""
-        extra = extra or {}
-        self.wait()
+        with span("khaos.ckpt.save", step=step):
+            return self._savepoint(step, state, timestamp, extra or {})
+
+    def _savepoint(self, step: int, state: Any, timestamp: float,
+                   extra: dict) -> SaveReport:
+        with span("khaos.ckpt.commit_wait"):
+            self.wait()
         t0 = time.monotonic()
-        snap = ChunkedHostSnapshot(state, self.plan.chunk_bytes,
-                                   defer_device=not self.plan.eager_snapshot)
+        with span("khaos.ckpt.snapshot"):
+            snap = ChunkedHostSnapshot(
+                state, self.plan.chunk_bytes,
+                defer_device=not self.plan.eager_snapshot)
+            if self.plan.encode_placement == "device":
+                # the savepoint anchors a fresh delta chain; refresh the
+                # device-resident base so post-drain deltas encode
+                # against it
+                self._device_base = DeviceDeltaBase(state)
         levels = []
         if "memory" in self.plan.levels:
             self._memory = (step, snap, dict(extra))
             self.saves_by_level["memory"] += 1
             levels.append("memory")
         self._base, self._base_step = snap, step
-        if self.plan.encode_placement == "device":
-            # the savepoint anchors a fresh delta chain; refresh the
-            # device-resident base so post-drain deltas encode against it
-            self._device_base = DeviceDeltaBase(state)
         nbytes, paths = 0, []
-        for level, store in self.stores.items():
-            paths.append(store.save(step, snap, timestamp,
-                                    {**extra, "kind": "full"}))
-            n = store.total_bytes(step)
-            nbytes += n
-            self.bytes_by_kind["full"] += n
-            self.saves_by_level[level] += 1
-            levels.append(level)
+        with span("khaos.ckpt.commit", step=step) as csp:
+            for level, store in self.stores.items():
+                paths.append(store.save(step, snap, timestamp,
+                                        {**extra, "kind": "full"}))
+                n = store.total_bytes(step)
+                nbytes += n
+                self.bytes_by_kind["full"] += n
+                self.saves_by_level[level] += 1
+                levels.append(level)
+            csp.add(bytes_written=nbytes)
         self.savepoints += 1
         self.policy.mark(timestamp)
         dur = time.monotonic() - t0
@@ -382,7 +407,15 @@ class CheckpointManager:
 
     def restore(self, treedef_like: Any,
                 failure_kind: str = "task") -> RestoreReport:
-        self.wait()
+        with span("khaos.ckpt.restore") as sp:
+            with span("khaos.ckpt.restore_wait"):
+                self.wait()
+            report = self._restore(treedef_like, failure_kind)
+            sp.step = report.step
+            sp.add(state_bytes=tree_bytes(report.state))
+        return report
+
+    def _restore(self, treedef_like: Any, failure_kind: str) -> RestoreReport:
         t0 = time.monotonic()
         allowed = allowed_levels(failure_kind,
                                  self.plan.effective_replication)
@@ -428,8 +461,14 @@ class CheckpointManager:
                 # decode where this plan encodes; blobs are byte-compatible
                 # across placements, so a host-written delta restores here
                 # and a device-written one restores under a host plan
-                state = apply_delta(store.directory, restore_step, state,
-                                    placement=self.plan.encode_placement)
+                with span("khaos.ckpt.apply_delta",
+                          step=restore_step) as asp:
+                    state = apply_delta(
+                        store.directory, restore_step, state,
+                        placement=self.plan.encode_placement)
+                    asp.add(bytes_read=sum(
+                        e.stat().st_size for e in os.scandir(
+                            delta_dir(store.directory, restore_step))))
                 extra = meta.get("extra", extra)
                 kind = "full+delta"
             report = RestoreReport(state, restore_step, level, kind,

@@ -97,6 +97,7 @@ import numpy as np
 # GROUP comes from the numpy-only oracle module so importing the pipeline
 # never pays for a pallas import (the jit'd ops load lazily, per call site)
 from repro.kernels.ckpt_delta.ref import GROUP
+from repro.utils.timing import current, span
 from repro.utils.trees import tree_flatten_with_names
 
 DEFAULT_CHUNK_BYTES = 4 << 20     # D2H granularity: first chunk = blocking
@@ -232,17 +233,19 @@ class ChunkedHostSnapshot(LeafSource):
 
         if chunks:      # first chunk synchronously: the device sync point
             self._leaves.update(self._materialize(chunks[0]))
-        pool = transfer_pool()
+        pool, parent = transfer_pool(), current()
         for chunk in chunks[1:]:
-            fut = pool.submit(self._materialize, chunk)
+            fut = pool.submit(self._materialize, chunk, parent)
             for name, _ in chunk:
                 self._future_of[name] = fut
 
-    @staticmethod
-    def _materialize(chunk: list) -> dict[str, np.ndarray]:
+    def _materialize(self, chunk: list,
+                     parent: Optional[int] = None) -> dict[str, np.ndarray]:
         # np.asarray on a jax.Array is the D2H copy (on the CPU backend it
         # may alias the immutable buffer, which is equally safe)
-        return {name: np.asarray(leaf) for name, leaf in chunk}
+        with span("khaos.ckpt.d2h", parent=parent,
+                  bytes=sum(self.nbytes(name) for name, _ in chunk)):
+            return {name: np.asarray(leaf) for name, leaf in chunk}
 
     def spec(self, name: str) -> tuple[tuple, np.dtype]:
         return self._spec[name]
@@ -539,12 +542,14 @@ class DeltaLeafSource(LeafSource):
         if not tasks:
             return
         self._pull_chunk(*tasks[0])
-        pool = transfer_pool()
-        self._chunk_futs = [pool.submit(self._pull_chunk, *task)
+        pool, parent = transfer_pool(), current()
+        self._chunk_futs = [pool.submit(self._pull_chunk, *task, parent)
                             for task in tasks[1:]]
 
-    def _pull_chunk(self, host: np.ndarray, dev: Any, a: int, b: int) -> None:
-        host[a:b] = np.asarray(dev[a:b])
+    def _pull_chunk(self, host: np.ndarray, dev: Any, a: int, b: int,
+                    parent: Optional[int] = None) -> None:
+        with span("khaos.ckpt.d2h", parent=parent, bytes=host[a:b].nbytes):
+            host[a:b] = np.asarray(dev[a:b])
         self._account(host[a:b].nbytes)
 
     def _account(self, nbytes: int) -> None:

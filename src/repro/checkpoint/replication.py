@@ -46,7 +46,8 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
-from repro.checkpoint.store import CheckpointStore, as_leaf_dtype
+from repro.checkpoint.store import CheckpointStore, as_leaf_dtype, load_npz
+from repro.utils.timing import current
 
 
 class ReplicationError(RuntimeError):
@@ -304,13 +305,11 @@ class PeerReplicatedStore(CheckpointStore):
             else:
                 missing.append(fname)
 
-        def load_npz(fname: str) -> dict[str, np.ndarray]:
-            fpath = os.path.join(self.directory, name, fname)
-            with np.load(fpath) as z:
-                return {k.replace("::", "/"): z[k] for k in z.files}
-
         data: dict[str, np.ndarray] = {}
-        futs = [(src, fname, io_pool().submit(load_npz, fname))
+        parent = current()
+        futs = [(src, fname, io_pool().submit(
+                    load_npz, os.path.join(self.directory, name, fname),
+                    step, parent))
                 for src, fname in plan]
         for src, fname, fut in futs:
             data.update(fut.result())

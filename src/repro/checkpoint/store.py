@@ -34,6 +34,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 import zstandard
 
+from repro.utils.timing import current, span
 from repro.utils.trees import tree_flatten_with_names
 
 import jax
@@ -173,6 +174,28 @@ class CheckpointMeta:
         return f"step_{self.step:010d}"
 
 
+def _crc_file(path: str) -> int:
+    """CRC32 of a file's bytes, read under a ``khaos.ckpt.verify`` span."""
+    with span("khaos.ckpt.verify") as sp, open(path, "rb") as f:
+        data = f.read()
+        sp.add(bytes_read=len(data))
+        return zlib.crc32(data)
+
+
+def load_npz(fpath: str, step: int, parent: Optional[int],
+             wanted: Optional[set] = None) -> dict[str, np.ndarray]:
+    """A shard file's leaves by name (all, or those in ``wanted``), read
+    under a ``khaos.ckpt.read`` span below ``parent``."""
+    out: dict[str, np.ndarray] = {}
+    with span("khaos.ckpt.read", step=step, parent=parent,
+              bytes_read=os.path.getsize(fpath)), np.load(fpath) as z:
+        for k in z.files:
+            name = k.replace("::", "/")
+            if wanted is None or name in wanted:
+                out[name] = z[k]
+    return out
+
+
 def _assign_shards(sizes_by_name: list[tuple[str, int]], num_shards: int):
     """Greedy balanced bin-packing of leaves into shards by bytes."""
     sizes = sorted(((nb, name) for name, nb in sizes_by_name), reverse=True)
@@ -226,6 +249,7 @@ class CheckpointStore:
         name = f"step_{step:010d}"
         path = os.path.join(self.directory, name)
         tmp = fresh_tmp_dir(path)
+        parent = current()      # the shard workers' spans hang under it
 
         def write_shard(j: int) -> tuple[str, int]:
             from repro.checkpoint.replication import retry_with_backoff
@@ -241,9 +265,11 @@ class CheckpointStore:
             def attempt() -> int:
                 if self.fault_hook is not None:
                     self.fault_hook(fpath)
-                np.savez(fpath, **shard)
-                with open(fpath, "rb") as f:
-                    return zlib.crc32(f.read())
+                with span("khaos.ckpt.write", step=step,
+                          parent=parent) as sp:
+                    np.savez(fpath, **shard)
+                    sp.add(bytes_written=os.path.getsize(fpath))
+                    return _crc_file(fpath)
 
             def note_retry(i: int, e: BaseException) -> None:
                 self.write_retries += 1
@@ -337,8 +363,7 @@ class CheckpointStore:
         fpath = os.path.join(self.directory, name, fname)
         if not os.path.exists(fpath):
             return False
-        with open(fpath, "rb") as f:
-            return zlib.crc32(f.read()) == crc
+        return _crc_file(fpath) == crc
 
     def _valid(self, name: str) -> Optional[dict]:
         manifest = self._manifest(name)
@@ -373,14 +398,12 @@ class CheckpointStore:
         if manifest is None:
             raise FileNotFoundError(f"checkpoint {name} is corrupt or missing")
         from repro.checkpoint.pipeline import io_pool
-
-        def load_shard(j: int) -> dict[str, np.ndarray]:
-            fpath = os.path.join(self.directory, name, f"shard_{j:05d}.npz")
-            with np.load(fpath) as z:
-                return {k.replace("::", "/"): z[k] for k in z.files}
-
+        parent = current()
         data: dict[str, np.ndarray] = {}
-        for fut in [io_pool().submit(load_shard, j)
+        for fut in [io_pool().submit(
+                        load_npz, os.path.join(self.directory, name,
+                                               f"shard_{j:05d}.npz"),
+                        step, parent)
                     for j in range(manifest["num_shards"])]:
             data.update(fut.result())
         names = [n for n, _ in tree_flatten_with_names(treedef_like)]
@@ -410,15 +433,12 @@ class CheckpointStore:
             raise KeyError(f"checkpoint missing leaves: {missing[:5]}...")
         wanted = set(names)
         from repro.checkpoint.pipeline import io_pool
-
-        def load_shard(j: int) -> dict[str, np.ndarray]:
-            fpath = os.path.join(self.directory, name, f"shard_{j:05d}.npz")
-            with np.load(fpath) as z:
-                return {k.replace("::", "/"): z[k] for k in z.files
-                        if k.replace("::", "/") in wanted}
-
+        parent = current()
         data: dict[str, np.ndarray] = {}
-        for fut in [io_pool().submit(load_shard, j)
+        for fut in [io_pool().submit(
+                        load_npz, os.path.join(self.directory, name,
+                                               f"shard_{j:05d}.npz"),
+                        step, parent, wanted)
                     for j in sorted({assign[n] for n in names})]:
             data.update(fut.result())
         return data

@@ -470,7 +470,14 @@ def make_loss_fn(cfg: ModelConfig, ann=L.NULL_ANN):
 def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
                     opt_cfg: OptimizerConfig, accum: int = 1, ann=L.NULL_ANN,
                     accum_dtype: str = "float32"):
-    loss_fn = make_loss_fn(cfg, ann=ann)
+    model_loss = make_loss_fn(cfg, ann=ann)
+
+    def loss_fn(params, batch):
+        # names the forward ops, and the backward ops that inherit it, in
+        # the device trace; XLA computes the same program
+        with jax.named_scope("model"):
+            return model_loss(params, batch)
+
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
     adt = jnp.dtype(accum_dtype)
 
@@ -505,8 +512,10 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
             loss = loss / accum
             metrics = {"ce": loss, "aux": jnp.zeros((), jnp.float32)}
 
-        grads, gnorm = clip_by_global_norm(grads, opt_cfg.grad_clip)
-        new_params, new_opt = optimizer.update(grads, state["opt"], params, state["step"])
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, opt_cfg.grad_clip)
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   params, state["step"])
         new_state = {"params": new_params, "opt": new_opt, "step": state["step"] + 1}
         out_metrics = {"loss": loss, "grad_norm": gnorm, **metrics}
         return new_state, out_metrics

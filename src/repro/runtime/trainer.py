@@ -42,6 +42,7 @@ from repro.ft.failures import Degradation, InjectedFailure, jitter_phase
 from repro.metrics import MetricsStore
 from repro.models import zoo
 from repro.optim import make_optimizer
+from repro.utils.timing import span
 from repro.utils.trees import place_like
 
 
@@ -234,18 +235,22 @@ class ResilientTrainer:
 
     def _restore(self, failure_kind: str = "node",
                  host: Optional[int] = None) -> None:
-        self.ckpt.on_failure(failure_kind, host=host)
-        # samples taken while catching up after the rollback reflect the
-        # failure, not steady state — hold healthy() low for a grace window
-        self._unhealthy_until = self.t + self.tcfg.detect_s + self.tcfg.restart_s
-        try:
-            report = self.ckpt.restore(self.state, failure_kind)
-        except FileNotFoundError:
-            self.events.append({"t": self.t, "event": "restore_fresh"})
-            return
-        # each leaf goes back with the sharding the live state had
-        self.state = place_like(report.state, self.state)
-        self.batcher.restore(report.extra["pipeline"])
+        with span("khaos.restore"):
+            self.ckpt.on_failure(failure_kind, host=host)
+            # samples taken while catching up after the rollback reflect
+            # the failure, not steady state — hold healthy() low for a
+            # grace window
+            self._unhealthy_until = (self.t + self.tcfg.detect_s
+                                     + self.tcfg.restart_s)
+            try:
+                report = self.ckpt.restore(self.state, failure_kind)
+            except FileNotFoundError:
+                self.events.append({"t": self.t, "event": "restore_fresh"})
+                return
+            # each leaf goes back with the sharding the live state had
+            with span("khaos.restore.place", step=report.step):
+                self.state = place_like(report.state, self.state)
+            self.batcher.restore(report.extra["pipeline"])
         self.events.append({"t": self.t, "event": "restore",
                             "step": report.step, "level": report.level,
                             "kind": report.kind,
@@ -283,42 +288,58 @@ class ResilientTrainer:
             if self.t >= self._dg_step_until:
                 self._dg_step_factor = 1.0
             self.stream.produce_until(self.t)
-            if self.policy.due(self.t):
-                if self.t < self._dg_bp_until:
-                    # backpressure: the barrier can't complete — hold the
-                    # trigger, counting each missed cadence slot once
-                    slot = self.policy.next_due(self.t)
-                    if slot != self._bp_last_slot:
-                        self._bp_last_slot = slot
-                        self.bp_suppressed += 1
-                        self.events.append({"t": self.t,
-                                            "event": "backpressure_skip"})
-                else:
-                    # only the blocking part (sync write, or async snapshot)
-                    # advances the virtual job clock
-                    blocking = self._checkpoint()
-                    if self.t < self._dg_ck_until:
-                        blocking += self._dg_ck_delay + self._dg_ck_jitter \
-                            * float(jitter_phase(self.t, self._dg_ck_t0))
-                    self.t += blocking * self.tcfg.time_scale
+            # an iteration that finds no batch trains nothing: tokens 0
+            with span("khaos.step", tokens=0) as sp:
+                self._iteration(sp, on_second)
+
+    def _trigger(self) -> None:
+        """Run the checkpoint trigger if one is due."""
+        if not self.policy.due(self.t):
+            return
+        if self.t < self._dg_bp_until:
+            # backpressure: the barrier can't complete — hold the
+            # trigger, counting each missed cadence slot once
+            slot = self.policy.next_due(self.t)
+            if slot != self._bp_last_slot:
+                self._bp_last_slot = slot
+                self.bp_suppressed += 1
+                self.events.append({"t": self.t,
+                                    "event": "backpressure_skip"})
+            return
+        # only the blocking part (sync write, or async snapshot)
+        # advances the virtual job clock
+        blocking = self._checkpoint()
+        if self.t < self._dg_ck_until:
+            blocking += self._dg_ck_delay + self._dg_ck_jitter \
+                * float(jitter_phase(self.t, self._dg_ck_t0))
+        self.t += blocking * self.tcfg.time_scale
+
+    def _iteration(self, sp, on_second) -> None:
+        """One pass of the loop: the trigger, then one train step."""
+        with span("khaos.trigger"):
+            self._trigger()
+        with span("khaos.batch"):
             batch = self.batcher.next_batch()
-            if batch is None:
-                self.t += 0.05        # idle: stream underrun
-                continue
-            w0 = time.monotonic()
+        if batch is None:
+            self.t += 0.05        # idle: stream underrun
+            return
+        w0 = time.monotonic()
+        with span("khaos.upload"):
             bt = {"tokens": jnp.asarray(batch["tokens"]),
                   "labels": jnp.asarray(batch["labels"])}
+        with span("khaos.dispatch"):
             self.state, metrics = self.step_fn(self.state, bt)
+        with span("khaos.loss_read"):
             loss = float(metrics["loss"])
-            wall = time.monotonic() - w0
-            self._measured_step_s = wall
+        wall = time.monotonic() - w0
+        sp.add(tokens=bt["tokens"].size)
+        self._measured_step_s = wall
+        with span("khaos.record"):
             # a straggler window inflates the virtual step time — the job
             # runs slower without any failure event firing (gray, not dead)
             step_s = wall * self._dg_step_factor
             self.t += step_s * self.tcfg.time_scale
             self.losses.append(loss)
-            self.metrics.record("loss", self.t, loss)
-            self.metrics.record("step_time", self.t, step_s)
             self.metrics.record("consumer_lag", self.t, self.stream.lag)
             self.metrics.record("arrival_rate", self.t,
                                 self.stream.rate_at(self.t))
@@ -327,7 +348,8 @@ class ResilientTrainer:
                 lat += self._dg_lat_delay + self._dg_lat_jitter \
                     * float(jitter_phase(self.t, self._dg_lat_t0))
             self.metrics.record("latency", self.t, lat)
-            if on_second is not None:
+        if on_second is not None:
+            with span("khaos.callback"):
                 on_second({"t": self.t, "loss": loss, "lag": self.stream.lag})
 
     # ------------------------------------------------------------------

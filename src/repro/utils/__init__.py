@@ -5,7 +5,6 @@ from repro.utils.trees import (
     tree_allclose,
     tree_zeros_like,
 )
-from repro.utils.timing import Timer, now
 
 __all__ = [
     "tree_bytes",
@@ -13,6 +12,4 @@ __all__ = [
     "tree_flatten_with_names",
     "tree_allclose",
     "tree_zeros_like",
-    "Timer",
-    "now",
 ]

@@ -117,6 +117,9 @@ class RestoreReport:
     restored_bytes: int = 0         # bytes PULLED to rebuild dead shards —
                                     # the recovery-drill gate compares this
                                     # against the full checkpoint size
+    fallbacks: int = 0              # fulls that failed their load (a
+                                    # missing or corrupt shard) before the
+                                    # restored one loaded whole
 
 
 @runtime_checkable
@@ -412,26 +415,69 @@ class CheckpointManager:
                 self.wait()
             report = self._restore(treedef_like, failure_kind)
             sp.step = report.step
-            sp.add(state_bytes=tree_bytes(report.state))
+            sp.add(state_bytes=tree_bytes(report.state),
+                   fallbacks=report.fallbacks)
         return report
+
+    def _manifest_candidates(self, level: str, rejected: set
+                             ) -> list[tuple[int, int]]:
+        """(restore_step, base_full_step) for each full of a plain level
+        that has a manifest and is not in ``rejected``, newest full first:
+        matched with the level's newest delta as ``_disk_candidate`` does.
+        No shard is read; the load checks them."""
+        store = self.stores[level]
+        dstep = newest_delta_step(store.directory)
+        meta = (read_delta_manifest(store.directory, dstep)
+                if dstep is not None else None)
+        base = meta["base_step"] if meta is not None else None
+        return [(dstep if full == base and dstep > full else full, full)
+                for full in reversed(store.manifest_steps())
+                if full not in rejected]
 
     def _restore(self, treedef_like: Any, failure_kind: str) -> RestoreReport:
         t0 = time.monotonic()
         allowed = allowed_levels(failure_kind,
                                  self.plan.effective_replication)
-        candidates: list[tuple[int, int, str]] = []   # (step, speed, level)
         speed = {"memory": 2, "local": 1, "remote": 0}
-        if "memory" in allowed and self._memory is not None:
-            candidates.append((self._memory[0], speed["memory"], "memory"))
-        for level in ("local", "remote"):
-            if level in allowed:
-                cand = self._disk_candidate(level)
-                if cand is not None:
-                    candidates.append((cand[0], speed[level], level))
-        if not candidates:
-            raise FileNotFoundError(
-                f"no checkpoint survives a {failure_kind} failure")
-        step, _, level = max(candidates)
+        levels = [l for l in ("local", "remote")
+                  if l in allowed and l in self.stores]
+        # each level's candidate is chosen once: a replicated level's from
+        # its validated steps (a degraded step still counts); a plain
+        # level's from its manifests, confirmed by loading its full, which
+        # reads and checks each shard once.  A full that fails its load is
+        # rejected and the choice made again, as if it were not on disk;
+        # a level counts at the best its unrejected fulls could give until
+        # its newest one has loaded
+        chosen = {l: self._disk_candidate(l) for l in levels
+                  if isinstance(self.stores[l], PeerReplicatedStore)}
+        loaded: dict[str, tuple[Any, dict]] = {}
+        rejected: dict[str, set] = {l: set() for l in levels}
+        fallbacks = 0
+        while True:
+            pending = {l: self._manifest_candidates(l, rejected[l])
+                       for l in levels if l not in chosen}
+            candidates = [(c[0], speed[l], l) for l, c in chosen.items()
+                          if c is not None]
+            candidates += [(max(r for r, _ in c), speed[l], l)
+                           for l, c in pending.items() if c]
+            if "memory" in allowed and self._memory is not None:
+                candidates.append((self._memory[0], speed["memory"],
+                                   "memory"))
+            if not candidates:
+                raise FileNotFoundError(
+                    f"no checkpoint survives a {failure_kind} failure")
+            _, _, level = max(candidates)
+            if level not in pending:
+                break
+            restore_step, full_step = pending[level][0]
+            try:
+                loaded[level] = self.stores[level].restore(treedef_like,
+                                                           full_step)
+            except FileNotFoundError:
+                rejected[level].add(full_step)
+                fallbacks += 1
+                continue
+            chosen[level] = (restore_step, full_step)
         if level == "memory":
             mstep, snap, extra = self._memory
             # deep copy so the caller can't corrupt the parked snapshot
@@ -441,20 +487,21 @@ class CheckpointManager:
                                    time.monotonic() - t0, dict(extra))
         else:
             store = self.stores[level]
-            restore_step, full_step = self._disk_candidate(level)
+            restore_step, full_step = chosen[level]
             degraded, restored_bytes = False, 0
-            if isinstance(store, PeerReplicatedStore):
-                # degraded partial restore: dead shards come from peer
-                # replicas, and a shard with NO local copy falls back
-                # per-shard to the remote store at the same step
+            if level in loaded:
+                state, extra = loaded[level]
+            else:
+                # the replicated store's degraded partial restore: dead
+                # shards come from peer replicas, and a shard with NO local
+                # copy falls back per-shard to the remote store at the same
+                # step
                 remote = self.stores.get("remote")
                 fallback = remote.read_leaves if remote is not None else None
                 state, extra = store.restore(treedef_like, full_step,
                                              shard_fallback=fallback)
                 degraded = store.last_restore.get("degraded", False)
                 restored_bytes = store.last_restore.get("restored_bytes", 0)
-            else:
-                state, extra = store.restore(treedef_like, full_step)
             kind = "full"
             if restore_step > full_step:
                 meta = read_delta_manifest(store.directory, restore_step)
@@ -475,6 +522,7 @@ class CheckpointManager:
                                    time.monotonic() - t0, extra,
                                    degraded=degraded,
                                    restored_bytes=restored_bytes)
+        report.fallbacks = fallbacks
         self.restores.append((report.step, report.level, report.kind))
         return report
 

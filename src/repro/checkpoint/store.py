@@ -23,6 +23,7 @@ logic (DESIGN.md §5).
 """
 from __future__ import annotations
 
+import io
 import json
 import os
 import shutil
@@ -186,13 +187,39 @@ def load_npz(fpath: str, step: int, parent: Optional[int],
              wanted: Optional[set] = None) -> dict[str, np.ndarray]:
     """A shard file's leaves by name (all, or those in ``wanted``), read
     under a ``khaos.ckpt.read`` span below ``parent``."""
-    out: dict[str, np.ndarray] = {}
     with span("khaos.ckpt.read", step=step, parent=parent,
               bytes_read=os.path.getsize(fpath)), np.load(fpath) as z:
-        for k in z.files:
-            name = k.replace("::", "/")
-            if wanted is None or name in wanted:
-                out[name] = z[k]
+        return _leaves(z, wanted)
+
+
+def read_shard(fpath: str, crc: int, step: int, parent: Optional[int],
+               wanted: Optional[set] = None
+               ) -> Optional[dict[str, np.ndarray]]:
+    """A shard file's leaves by name (all, or those in ``wanted``), or None
+    when the file is missing or unreadable or its bytes fail ``crc``.  The
+    file is read once, under a ``khaos.ckpt.read`` span below ``parent``;
+    the CRC (its own ``khaos.ckpt.verify`` span, no bytes counted again) is
+    taken of those bytes and the arrays are built from the same bytes."""
+    with span("khaos.ckpt.read", step=step, parent=parent) as sp:
+        try:
+            with open(fpath, "rb") as f:
+                data = f.read()
+        except OSError:
+            return None
+        sp.add(bytes_read=len(data))
+        with span("khaos.ckpt.verify"):
+            if zlib.crc32(data) != crc:
+                return None
+        with np.load(io.BytesIO(data)) as z:
+            return _leaves(z, wanted)
+
+
+def _leaves(z, wanted: Optional[set]) -> dict[str, np.ndarray]:
+    out: dict[str, np.ndarray] = {}
+    for k in z.files:
+        name = k.replace("::", "/")
+        if wanted is None or name in wanted:
+            out[name] = z[k]
     return out
 
 
@@ -374,38 +401,73 @@ class CheckpointStore:
                 return None
         return manifest
 
+    def _step_names(self) -> list[str]:
+        return [n for n in sorted(os.listdir(self.directory))
+                if n.startswith("step_") and not n.endswith(".tmp")]
+
     def list_steps(self) -> list[int]:
-        out = []
-        for name in sorted(os.listdir(self.directory)):
-            if name.startswith("step_") and not name.endswith(".tmp"):
-                if self._valid(name) is not None:
-                    out.append(int(name.split("_")[1]))
-        return out
+        return [int(n.split("_")[1]) for n in self._step_names()
+                if self._valid(n) is not None]
 
     def newest(self) -> Optional[int]:
         steps = self.list_steps()
         return steps[-1] if steps else None
 
+    def manifest_steps(self) -> list[int]:
+        """Steps whose manifest loads, oldest first, with no shard read:
+        the candidates a restore checks by loading them."""
+        return [int(n.split("_")[1]) for n in self._step_names()
+                if self._manifest(n) is not None]
+
     # -- restore ---------------------------------------------------------------
-    def restore(self, treedef_like: Any, step: Optional[int] = None) -> tuple[Any, dict]:
-        """Restore into the structure of ``treedef_like`` (a pytree of arrays
-        or ShapeDtypeStructs).  Returns (state, extra)."""
-        step = step if step is not None else self.newest()
-        if step is None:
-            raise FileNotFoundError("no valid checkpoint found")
+    def _load(self, step: int, wanted: Optional[set] = None
+              ) -> tuple[dict, dict[str, np.ndarray]]:
+        """(manifest, leaves by name) of ``step``, each shard file read once
+        on the io pool and checked against its manifest CRC before its
+        arrays are built (with ``wanted``, only the shards holding those
+        leaves, and ``KeyError`` when one is not in the manifest).  Raises
+        ``FileNotFoundError`` unless every shard read loads whole."""
+        from repro.checkpoint.pipeline import io_pool
+
         name = f"step_{step:010d}"
-        manifest = self._valid(name)
+        manifest = self._manifest(name)
         if manifest is None:
             raise FileNotFoundError(f"checkpoint {name} is corrupt or missing")
-        from repro.checkpoint.pipeline import io_pool
+        shards = None
+        if wanted is not None:
+            absent = sorted(wanted - manifest["assign"].keys())
+            if absent:
+                raise KeyError(f"checkpoint missing leaves: {absent[:5]}...")
+            shards = {f"shard_{manifest['assign'][n]:05d}.npz"
+                      for n in wanted}
         parent = current()
+        futs = [io_pool().submit(read_shard,
+                                 os.path.join(self.directory, name, fname),
+                                 crc, step, parent, wanted)
+                for fname, crc in manifest["checksums"].items()
+                if shards is None or fname in shards]
+        parts = [f.result() for f in futs]
+        if any(p is None for p in parts):
+            raise FileNotFoundError(f"checkpoint {name} is corrupt or missing")
         data: dict[str, np.ndarray] = {}
-        for fut in [io_pool().submit(
-                        load_npz, os.path.join(self.directory, name,
-                                               f"shard_{j:05d}.npz"),
-                        step, parent)
-                    for j in range(manifest["num_shards"])]:
-            data.update(fut.result())
+        for p in parts:
+            data.update(p)
+        return manifest, data
+
+    def restore(self, treedef_like: Any, step: Optional[int] = None) -> tuple[Any, dict]:
+        """Restore into the structure of ``treedef_like`` (a pytree of arrays
+        or ShapeDtypeStructs).  Returns (state, extra).  With no ``step``,
+        the newest step that loads whole (the step ``newest()`` names)."""
+        for cand in ([step] if step is not None
+                     else reversed(self.manifest_steps())):
+            try:
+                manifest, data = self._load(cand)
+                break
+            except FileNotFoundError:
+                if step is not None:
+                    raise
+        else:
+            raise FileNotFoundError("no valid checkpoint found")
         names = [n for n, _ in tree_flatten_with_names(treedef_like)]
         missing = [n for n in names if n not in data]
         if missing:
@@ -423,25 +485,7 @@ class CheckpointStore:
         host's leaves, never the whole checkpoint.  Leaf names are
         layout-independent, so a remote store with a different shard
         assignment serves a local store's missing shard correctly."""
-        name = f"step_{step:010d}"
-        manifest = self._valid(name)
-        if manifest is None:
-            raise FileNotFoundError(f"checkpoint {name} is corrupt or missing")
-        assign = manifest["assign"]
-        missing = [n for n in names if n not in assign]
-        if missing:
-            raise KeyError(f"checkpoint missing leaves: {missing[:5]}...")
-        wanted = set(names)
-        from repro.checkpoint.pipeline import io_pool
-        parent = current()
-        data: dict[str, np.ndarray] = {}
-        for fut in [io_pool().submit(
-                        load_npz, os.path.join(self.directory, name,
-                                               f"shard_{j:05d}.npz"),
-                        step, parent, wanted)
-                    for j in sorted({assign[n] for n in names})]:
-            data.update(fut.result())
-        return data
+        return self._load(step, set(names))[1]
 
     def total_bytes(self, step: int) -> int:
         name = f"step_{step:010d}"

@@ -78,6 +78,134 @@ def test_store_atomicity_corrupt_shard_falls_back(tmp_path):
     assert tree_allclose(s1, restored)
 
 
+def _manager(path, levels=("local",)):
+    from repro.checkpoint import CheckpointManager, CheckpointPlan
+    return CheckpointManager(str(path), CheckpointPlan(
+        mode="full", levels=levels, num_shards=2, keep=5, remote_every=1,
+        replication_factor=0))
+
+
+def _shards(directory, step):
+    d = os.path.join(str(directory), f"step_{step:010d}")
+    return sorted(os.path.join(d, f) for f in os.listdir(d)
+                  if f.startswith("shard_"))
+
+
+def _below(spans, root):
+    """``root``'s spans and every span below it."""
+    ids, out = {root.id}, [root]
+    for s in spans:                 # oldest first: parents open first
+        if s.parent in ids:
+            ids.add(s.id)
+            out.append(s)
+    return out
+
+
+def _corrupt_byte(path):
+    with open(path, "r+b") as f:
+        f.seek(os.path.getsize(path) // 2)
+        b = f.read(1)
+        f.seek(-1, os.SEEK_CUR)
+        f.write(bytes([b[0] ^ 0xFF]))
+
+
+def test_restore_reads_each_shard_byte_once(tmp_path):
+    import time
+    from repro.utils.timing import spans
+    mgr = _manager(tmp_path)
+    mgr.save(1, _state(1))
+    mgr.save(2, _state(2))
+    t0 = time.perf_counter()
+    rep = mgr.restore(_state(), "task")
+    got, dropped = spans(t0, time.perf_counter())
+    assert dropped == 0 and rep.step == 2 and rep.fallbacks == 0
+    [root] = [s for s in got if s.name == "khaos.ckpt.restore"]
+    below = _below(got, root)
+    read = sum(s.counts.get("bytes_read", 0) for s in below)
+    assert read == sum(os.path.getsize(p)
+                       for p in _shards(tmp_path / "local", 2))
+    # the CRC runs over the bytes already read and counts none again
+    assert {s.name for s in below if s.counts.get("bytes_read")} == {
+        "khaos.ckpt.read"}
+    assert root.counts["fallbacks"] == 0
+    assert tree_allclose(rep.state, _state(2))
+
+
+@pytest.mark.parametrize("fault", ["corrupt_byte", "deleted_shard"])
+def test_manager_restore_falls_back_past_a_faulty_newest_full(tmp_path,
+                                                              fault):
+    import time
+    from repro.utils.timing import spans
+    mgr = _manager(tmp_path)
+    mgr.save(1, _state(1), extra={"i": 1})
+    mgr.save(2, _state(2), extra={"i": 2})
+    shard = _shards(tmp_path / "local", 2)[1]
+    if fault == "corrupt_byte":
+        _corrupt_byte(shard)
+    else:
+        os.remove(shard)
+    t0 = time.perf_counter()
+    rep = mgr.restore(_state(), "task")
+    got, _ = spans(t0, time.perf_counter())
+    assert (rep.step, rep.level, rep.kind) == (1, "local", "full")
+    assert rep.extra["i"] == 1 and rep.fallbacks == 1
+    assert tree_allclose(rep.state, _state(1))
+    [root] = [s for s in got if s.name == "khaos.ckpt.restore"]
+    assert root.counts["fallbacks"] == 1 and root.step == 1
+    # what the validated listing names is what the restore chose
+    assert mgr.restore_candidates()[0] == (1, "local")
+
+
+def test_restore_of_a_corrupt_step_by_number_raises(tmp_path):
+    store = CheckpointStore(str(tmp_path), num_shards=2, keep=5)
+    store.save(1, _state(1))
+    store.save(2, _state(2))
+    _corrupt_byte(_shards(tmp_path, 2)[0])
+    with pytest.raises(FileNotFoundError, match="corrupt or missing"):
+        store.restore(_state(), step=2)
+    restored, _ = store.restore(_state(), step=1)
+    assert tree_allclose(restored, _state(1))
+
+
+def test_a_rejected_full_hands_the_restore_to_the_next_level(tmp_path):
+    """The choice made again after a failed load is the one the validated
+    listing makes: local's newest full is corrupt, and remote's newer than
+    local's older one wins."""
+    mgr = _manager(tmp_path, levels=("local", "remote"))
+    for i in (1, 2):
+        mgr.save(i, _state(i))
+    mgr.stores["remote"].save(3, _state(3))
+    mgr.stores["local"].save(4, _state(4))
+    _corrupt_byte(_shards(tmp_path / "local", 4)[0])
+    expect = mgr.restore_candidates()[0]
+    rep = mgr.restore(_state(), "task")
+    assert (rep.step, rep.level) == expect == (3, "remote")
+    assert rep.fallbacks == 1 and tree_allclose(rep.state, _state(3))
+
+
+def test_a_rejected_full_leaves_the_delta_on_its_base(tmp_path):
+    """A local full written after a newer delta's base (a rolled-back
+    stream) fails its load: the restore takes that base and its delta over
+    the remote full between them, as the validated listing does."""
+    from repro.checkpoint import CheckpointManager, CheckpointPlan
+    mgr = CheckpointManager(str(tmp_path), CheckpointPlan(
+        mode="incremental", full_every=8, delta_codec="lossless",
+        levels=("local", "remote"), num_shards=2, keep=5,
+        replication_factor=0))
+    mgr.save(0, _state(0))
+    assert mgr.save(5, _state(5)).kind == "delta"
+    mgr.stores["remote"].save(4, _state(4))
+    mgr.stores["local"].save(3, _state(3))
+    rep = mgr.restore(_state(), "task")
+    assert (rep.step, rep.level, rep.fallbacks) == (4, "remote", 0)
+    _corrupt_byte(_shards(tmp_path / "local", 3)[0])
+    expect = mgr.restore_candidates()[0]
+    rep = mgr.restore(_state(), "task")
+    assert (rep.step, rep.level) == expect == (5, "local")
+    assert rep.kind == "full+delta" and rep.fallbacks == 1
+    assert tree_allclose(rep.state, _state(5))
+
+
 def test_store_missing_manifest_invisible(tmp_path):
     store = CheckpointStore(str(tmp_path), num_shards=1)
     store.save(5, _state())

@@ -1,6 +1,7 @@
-"""The benchmark's arithmetic: operations and bytes from a
-configuration's shapes, the peak table, and the end-to-end metrics from
-the host-clock records of a run.  Nothing here imports the program."""
+"""The benchmark's arithmetic: operations and bytes from the shapes a
+configuration's architecture module gives, the peak table, and the
+end-to-end metrics from the host-clock records of a run.  Nothing here
+imports the program."""
 from __future__ import annotations
 
 import json
@@ -26,48 +27,14 @@ def peaks(device_kind: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# shapes of the dense decoder at a configuration's cut
+# operations and bytes
 # ---------------------------------------------------------------------------
 
-def padded_vocab(cfg: dict, multiple: int = 256) -> int:
-    return -(-cfg["vocab_size"] // multiple) * multiple
-
-
-def param_shapes(cfg: dict) -> dict:
-    """name -> shape of every parameter, as the trainer stores them: the
-    layers stacked on a leading axis, the vocabulary padded to 256."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, k = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, n = d // h, cfg["num_hidden_layers"]
-    v = padded_vocab(cfg)
-    shapes = {"emb/embed": (v, d), "final_norm/scale": (d,),
-              "layers/attn/wq": (n, d, h, hd), "layers/attn/wk": (n, d, k, hd),
-              "layers/attn/wv": (n, d, k, hd), "layers/attn/wo": (n, h, hd, d),
-              "layers/ffn/w_up": (n, d, f), "layers/ffn/w_gate": (n, d, f),
-              "layers/ffn/w_down": (n, f, d),
-              "layers/ln1/scale": (n, d), "layers/ln2/scale": (n, d)}
-    if not cfg.get("tie_word_embeddings", False):
-        shapes["emb/unembed"] = (d, v)
-    return shapes
-
-
-def matmul_params(cfg: dict) -> int:
-    """Parameters that take part in a matrix product per token: every
-    layer's projections and the output head at the real vocabulary; the
-    embedding lookup is not a product."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, k = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // h
-    layer = d * h * hd + 2 * d * k * hd + h * hd * d + 3 * d * f
-    return cfg["num_hidden_layers"] * layer + d * cfg["vocab_size"]
-
-
 def train_flops_per_token(cfg: dict, seq_len: int) -> float:
-    """Model FLOPs of one token of a train step: 6 per matmul parameter
-    (forward and backward) plus 12 * layers * d_model * seq_len for the
-    attention scores and their use; recomputation is not counted."""
-    return (6.0 * matmul_params(cfg)
-            + 12.0 * cfg["num_hidden_layers"] * cfg["hidden_size"] * seq_len)
+    """Model FLOPs of one token of a train step, as the configuration's
+    architecture module counts them."""
+    import harness
+    return harness.arch_module(cfg).train_flops_per_token(cfg, seq_len)
 
 
 def train_flops_per_step(cfg: dict) -> float:
@@ -84,7 +51,9 @@ def packed_groups(cfg: dict, f32_copies: int = 3) -> int:
     """Scale groups of the packed f32 state a device delta trigger
     encodes: each f32 leaf padded to whole groups; parameters and, with
     f32 Adam, both moments (``f32_copies`` = 3)."""
-    per = sum(groups(math.prod(s)) for s in param_shapes(cfg).values())
+    import harness
+    shapes = harness.arch_module(cfg).param_shapes(cfg)
+    per = sum(groups(math.prod(s)) for s in shapes.values())
     return f32_copies * per
 
 

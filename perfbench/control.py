@@ -82,9 +82,11 @@ def main() -> int:
                                          cfg["vocab_size"], pseed)
                   for o in range(0, 3 * batch, batch)]
         full = [(e[:, :-1], e[:, 1:]) for e in events]
-        ref = reference.first_steps(cfg, pseed, full)
+        arch = harness.arch_module(cfg)
+        ref = reference.first_steps(cfg, pseed, full, arch=arch)
         half = reference.first_steps(
-            cfg, pseed, [(t[: batch // 2], l[: batch // 2]) for t, l in full])
+            cfg, pseed, [(t[: batch // 2], l[: batch // 2]) for t, l in full],
+            arch=arch)
         out["fault_half_batch"] = {
             k: v for k, v in harness.training_numbers(half, ref).items()
             if k.endswith("gap")}

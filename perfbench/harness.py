@@ -13,10 +13,20 @@ harness only wraps the program's entry points on this one job's objects
 to time and record them, and raises ``StopRun`` from the per-step
 callback to end a phase.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file of its own, found by name:
-``configs/<config>.json``, ``traffic/<traffic>.json`` and
-``metrics/<metric>.py``.
+What belongs to one configuration, one traffic mix or one per-layer
+metric is in files of its own, found by name, so that a new one is added
+as files alone:
+
+  * a configuration is ``configs/<config>.json``: its sizes, its job, the
+    limits its training numbers are held to (``limits``, over the common
+    ones of ``limits.json``) and the name of its architecture (``arch``);
+  * an architecture is ``archs/<arch>.py``: everything that depends on the
+    model's shape (the program's ``ModelConfig``, the plain reference's
+    weights and loss, the parameters' shapes, the FLOPs of a token, and
+    the CPU tests' size);
+  * a traffic mix is ``traffic/<traffic>.json``;
+  * a per-layer metric is ``metrics/<metric>.py``, whose ``read`` takes a
+    ``RunView``.
 """
 from __future__ import annotations
 
@@ -42,7 +52,7 @@ from traffic import EventSource
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-LIMITS = json.loads((BENCH / "limits.json").read_text())
+COMMON_LIMITS = json.loads((BENCH / "limits.json").read_text())["limits"]
 
 
 class StopRun(Exception):
@@ -72,12 +82,23 @@ def load_mix(name: str, bench_dir: Path = BENCH) -> dict:
     return json.loads((bench_dir / "traffic" / f"{name}.json").read_text())
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH) -> Callable:
-    spec = importlib.util.spec_from_file_location(
-        f"perfbench_metric_{name}", bench_dir / "metrics" / f"{name}.py")
+def _load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH) -> Callable:
+    return _load_module(bench_dir / "metrics" / f"{name}.py",
+                        f"perfbench_metric_{name}").read
+
+
+def arch_module(cfg: dict, bench_dir: Path = BENCH):
+    """The configuration's architecture module, ``archs/<arch>.py``."""
+    arch = cfg["arch"]
+    return _load_module(bench_dir / "archs" / f"{arch}.py",
+                        f"perfbench_arch_{arch}")
 
 
 def cell_metrics(bench: dict, workload: str, kind: str) -> list:
@@ -95,20 +116,8 @@ def program_seed(seed: int) -> int:
 # the program's configuration objects, from the benchmark's files
 # ---------------------------------------------------------------------------
 
-def model_config(cfg: dict, **override):
-    from repro.config import ModelConfig
-    job = cfg["job"]
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        activation="swiglu", norm_type="rmsnorm",
-        norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg.get("tie_word_embeddings", False),
-        dtype=job["compute_dtype"],
-        param_dtype=override.get("param_dtype", job["param_dtype"]))
+def model_config(cfg: dict, bench_dir: Path = BENCH, **override):
+    return arch_module(cfg, bench_dir).model_config(cfg, **override)
 
 
 def optimizer_config(cfg: dict, **override):
@@ -223,7 +232,8 @@ class Job:
 
     def __init__(self, cfg: dict, mix: dict, seed: int, ckpt_dir: str,
                  model_override: Optional[dict] = None,
-                 opt_override: Optional[dict] = None):
+                 opt_override: Optional[dict] = None,
+                 bench_dir: Path = BENCH):
         from repro.config import CheckpointPlan, KhaosConfig
         from repro.core import KhaosRuntime, demo_prior_models
         from repro.runtime import (ResilientTrainer, TrainerConfig,
@@ -239,8 +249,9 @@ class Job:
                              ckpt_dir=ckpt_dir, time_scale=1.0,
                              detect_s=0.0, restart_s=0.0, plan=plan)
         self.trainer = ResilientTrainer(
-            model_config(cfg, **(model_override or {})), tcfg, self.source,
-            optimizer_config(cfg, **(opt_override or {})), seed=seed)
+            model_config(cfg, bench_dir, **(model_override or {})), tcfg,
+            self.source, optimizer_config(cfg, **(opt_override or {})),
+            seed=seed)
         self.rt = KhaosRuntime(
             KhaosConfig(latency_constraint=math.inf,
                         recovery_constraint=math.inf,
@@ -442,7 +453,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float,
              trace_dir: Optional[str], t_start: float,
              model_override: Optional[dict] = None,
              opt_override: Optional[dict] = None,
-             first_steps: int = 3) -> dict:
+             first_steps: int = 3, bench_dir: Path = BENCH) -> dict:
     """One run.  Returns the records, the metrics' raw inputs and the
     checks; ``summarize`` turns them into the result line."""
     import jax
@@ -450,15 +461,17 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float,
     ckpt_dir = tempfile.mkdtemp(prefix="perfbench-ckpt-")
     try:
         return _run(cell, cfg, mix, seed, pseed, seconds, trace_dir, t_start,
-                    ckpt_dir, model_override, opt_override, first_steps)
+                    ckpt_dir, model_override, opt_override, first_steps,
+                    bench_dir)
     finally:
         shutil.rmtree(ckpt_dir, ignore_errors=True)
 
 
 def _run(cell, cfg, mix, seed, pseed, seconds, trace_dir, t_start, ckpt_dir,
-         model_override, opt_override, first_steps) -> dict:
+         model_override, opt_override, first_steps, bench_dir) -> dict:
     import jax
-    job = Job(cfg, mix, pseed, ckpt_dir, model_override, opt_override)
+    job = Job(cfg, mix, pseed, ckpt_dir, model_override, opt_override,
+              bench_dir)
     tr, rec = job.trainer, job.rec
 
     # ---- set-up: the first steps (checked against the reference), then
@@ -569,7 +582,8 @@ def _run(cell, cfg, mix, seed, pseed, seconds, trace_dir, t_start, ckpt_dir,
         range(off, off + cfg["job"]["batch"]), cfg["job"]["seq_len"],
         cfg["vocab_size"], pseed) for off, _, _ in rec.batches]
     ref = reference.first_steps(cfg, pseed,
-                                [(e[:, :-1], e[:, 1:]) for e in events])
+                                [(e[:, :-1], e[:, 1:]) for e in events],
+                                arch=arch_module(cfg, bench_dir))
     nums = training_numbers(prog, ref)
     details.update(nums)
     checks["loss_gap"] = nums["loss_gap"]
@@ -732,16 +746,17 @@ def end_to_end(name: str, run: dict, cfg: dict) -> Optional[float]:
     raise KeyError(f"no end-to-end metric {name!r}")
 
 
-def limits(config: str) -> dict:
-    """The limits a configuration's numbers are held to."""
-    return {**LIMITS["limits"], **LIMITS["by_config"][config]}
+def limits(cfg: dict) -> dict:
+    """The limits a configuration's numbers are held to: its own over the
+    common ones."""
+    return {**COMMON_LIMITS, **cfg["limits"]}
 
 
-def compare(checks: dict, config: str) -> tuple[bool, dict]:
+def compare(checks: dict, cfg: dict) -> tuple[bool, dict]:
     """Each number beside its limit; correct when none is over.  A
     number whose limit is null is not compared for that configuration."""
     out, ok = {}, True
-    lim = limits(config)
+    lim = limits(cfg)
     for name, value in checks.items():
         limit = lim[name]
         if limit is None:
@@ -780,7 +795,7 @@ def result(bench: dict, cell: dict, cfg: dict, run: dict, device: dict,
             out["breakdown"] = {
                 "device_ops": [[k, v] for k, v in summary["device_ops"]],
                 "idle_gaps": [[k, v] for k, v in summary["idle_gaps"]]}
-    correct, checks = compare(run["checks"], cfg["name"])
+    correct, checks = compare(run["checks"], cfg)
     return {"correct": bool(correct), "attempted": attempted,
             "failed": int(failed), "metrics": metrics,
             "device": {**device, "memory_peak_bytes": run["memory_peak_bytes"]},

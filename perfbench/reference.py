@@ -1,13 +1,14 @@
 """Plain references the benchmark holds the program to.  Nothing here
 imports the program or takes anything it made.
 
-  * The dense decoder of a configuration at its cut, in float32 with
-    matrix products at ``highest`` precision: its initial weights from the
-    seed (the same draws the configuration's initializer makes), its
-    mean token cross-entropy and gradients, and AdamW with global-norm
-    clipping, warmup and cosine decay as the configuration states them.
-    A batch is processed one sequence at a time, and attention one block
-    of query rows at a time, so that the reference fits beside nothing.
+  * The first steps of training a configuration at its cut, in float32
+    with matrix products at ``highest`` precision: the model's initial
+    weights from the seed and its mean token cross-entropy come from the
+    configuration's architecture module (``archs/<arch>.py``), which
+    imports nothing of the program either; here are the gradients, and
+    AdamW with global-norm clipping, warmup and cosine decay as the
+    configuration states them.  A batch is processed one sequence at a
+    time, so that the reference fits beside nothing.
   * The events' tokens: event ``o`` is a sequence of ``seq_len + 1``
     tokens, t0 drawn from the seed and the offset, then
     t_{i+1} = (31 t_i + 7) mod vocab.
@@ -28,7 +29,6 @@ from functools import partial
 import numpy as np
 
 GROUP = 1024
-QUERY_BLOCK = 256
 
 
 # ---------------------------------------------------------------------------
@@ -47,122 +47,8 @@ def event_tokens(offsets, seq_len: int, vocab: int, seed: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# the dense decoder
+# the first steps of training
 # ---------------------------------------------------------------------------
-
-def _dims(cfg: dict) -> dict:
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    v = -(-cfg["vocab_size"] // 256) * 256
-    return dict(d=d, f=cfg["intermediate_size"], h=h,
-                k=cfg["num_key_value_heads"], hd=d // h,
-                n=cfg["num_hidden_layers"], v=v, vocab=cfg["vocab_size"],
-                eps=cfg["rms_norm_eps"], theta=cfg["rope_theta"])
-
-
-def init_params(cfg: dict, seed: int, dtype=None):
-    """Initial weights: truncated normals (+-3 sigma) of std 0.02, the
-    output projections of each layer at 0.02 / sqrt(2 * layers), norm
-    scales 1; the layers stacked on a leading axis; the vocabulary padded
-    to a multiple of 256."""
-    import jax
-    import jax.numpy as jnp
-    dt = dtype or jnp.float32
-    m = _dims(cfg)
-    d, f, h, k, hd, n, v = (m[x] for x in "d f h k hd n v".split())
-
-    def normal(key, shape, std):
-        return (std * jax.random.truncated_normal(
-            key, -3.0, 3.0, shape, jnp.float32)).astype(dt)
-
-    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
-    ke = jax.random.split(keys[0], 2)
-    out_std = 0.02 / math.sqrt(2 * n)
-
-    def layer(key):
-        ka, kf = jax.random.split(key, 2)
-        qa = jax.random.split(ka, 4)
-        qf = jax.random.split(kf, 3)
-        return {"attn": {"wq": normal(qa[0], (d, h, hd), 0.02),
-                         "wk": normal(qa[1], (d, k, hd), 0.02),
-                         "wv": normal(qa[2], (d, k, hd), 0.02),
-                         "wo": normal(qa[3], (h, hd, d), out_std)},
-                "ffn": {"w_up": normal(qf[0], (d, f), 0.02),
-                        "w_down": normal(qf[1], (f, d), out_std),
-                        "w_gate": normal(qf[2], (d, f), 0.02)},
-                "ln1": {"scale": jnp.ones((d,), dt)},
-                "ln2": {"scale": jnp.ones((d,), dt)}}
-
-    emb = {"embed": normal(ke[0], (v, d), 0.02)}
-    if not cfg.get("tie_word_embeddings", False):
-        emb["unembed"] = normal(ke[1], (d, v), 0.02)
-    return {"emb": emb, "final_norm": {"scale": jnp.ones((d,), dt)},
-            "layers": jax.vmap(layer)(jax.random.split(keys[1], n))}
-
-
-def _rms(x, scale, eps):
-    import jax
-    import jax.numpy as jnp
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
-
-
-def _rope(x, pos, theta):
-    import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    inv = 1.0 / (theta ** (np.arange(half, dtype=np.float32) / half))
-    ang = pos[:, None].astype(jnp.float32) * jnp.asarray(inv)[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def sequence_loss(params, tokens, labels, cfg: dict):
-    """Mean cross-entropy of one sequence (tokens, labels: (S,))."""
-    import jax
-    import jax.numpy as jnp
-    m = _dims(cfg)
-    S = tokens.shape[0]
-    pos = jnp.arange(S)
-    x = params["emb"]["embed"][tokens]
-
-    def layer(x, lp):
-        a = _rms(x, lp["ln1"]["scale"], m["eps"])
-        q = _rope(jnp.einsum("sd,dnh->snh", a, lp["attn"]["wq"]), pos,
-                  m["theta"])
-        k = _rope(jnp.einsum("sd,dnh->snh", a, lp["attn"]["wk"]), pos,
-                  m["theta"])
-        v = jnp.einsum("sd,dnh->snh", a, lp["attn"]["wv"])
-        rep = m["h"] // m["k"]
-        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-
-        # causal softmax attention over blocks of query rows, each
-        # recomputed in the backward pass, so that one block's scores are
-        # the largest temporary
-        @jax.checkpoint
-        def rows(args):
-            qb, qp = args
-            s = jnp.einsum("qnh,snh->nqs", qb, k) / math.sqrt(m["hd"])
-            s = jnp.where((qp[:, None] >= pos[None, :])[None], s, -jnp.inf)
-            return jnp.einsum("nqs,snh->qnh", jax.nn.softmax(s, axis=-1), v)
-
-        C = min(S, QUERY_BLOCK)
-        o = jax.lax.map(rows, (q.reshape(S // C, C, *q.shape[1:]),
-                               pos.reshape(S // C, C)))
-        o = o.reshape(q.shape)
-        x = x + jnp.einsum("qnh,nhd->qd", o, lp["attn"]["wo"])
-        b = _rms(x, lp["ln2"]["scale"], m["eps"])
-        g = jax.nn.silu(b @ lp["ffn"]["w_gate"]) * (b @ lp["ffn"]["w_up"])
-        return x + g @ lp["ffn"]["w_down"], None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    x = _rms(x, params["final_norm"]["scale"], m["eps"])
-    w = (params["emb"]["unembed"] if "unembed" in params["emb"]
-         else params["emb"]["embed"].T)
-    logits = (x @ w)[:, :m["vocab"]]          # padding rows never score
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - gold)
-
 
 def lr_at(step: int, opt: dict) -> float:
     warm = max(1, opt["warmup_steps"])
@@ -183,8 +69,10 @@ def _adamw_leaf(p, g, m, v, lr, clip, bc1, bc2, *, b1, b2, eps, wd):
     return p - lr * (delta + wd * p), m, v, jnp.sqrt(jnp.sum(g * g))
 
 
-def first_steps(cfg: dict, seed: int, batches: list, dtype=None) -> dict:
-    """The reference's first ``len(batches)`` steps from the seed's
+def first_steps(cfg: dict, seed: int, batches: list, dtype=None, *,
+                arch) -> dict:
+    """The first ``len(batches)`` steps of the architecture module
+    ``arch`` (its ``init_params`` and ``sequence_loss``) from the seed's
     weights: each step's loss, the per-leaf norm of the first clipped
     gradient, and the per-leaf norm of the parameters' change after the
     last step.  A step is the mean loss and gradient over the batch's
@@ -197,12 +85,13 @@ def first_steps(cfg: dict, seed: int, batches: list, dtype=None) -> dict:
     opt = cfg["job"]["optimizer"]
     b1, b2 = opt["b1"], opt["b2"]
     with jax.default_matmul_precision("highest"):
-        params = init_params(cfg, seed, dtype)
+        params = arch.init_params(cfg, seed, dtype)
         names = list(leaf_norms_names(params))
         P, treedef = jax.tree_util.tree_flatten(params)
         del params
         M = V = None
-        grad = jax.jit(jax.value_and_grad(partial(sequence_loss, cfg=cfg)))
+        grad = jax.jit(jax.value_and_grad(partial(arch.sequence_loss,
+                                                  cfg=cfg)))
         add = jax.jit(jnp.add, donate_argnums=0)
         scale = jax.jit(jnp.multiply, donate_argnums=0)
         sumsq = jax.jit(lambda x: jnp.sum(x * x))
@@ -242,7 +131,7 @@ def first_steps(cfg: dict, seed: int, batches: list, dtype=None) -> dict:
             if grad1 is None:
                 grad1 = dict(zip(names, norms))
         del M, V, acc
-        p0 = jax.tree_util.tree_leaves(init_params(cfg, seed, dtype))
+        p0 = jax.tree_util.tree_leaves(arch.init_params(cfg, seed, dtype))
         change = {}
         for j, name in enumerate(names):
             change[name] = math.sqrt(float(sumsq(P[j] - p0[j])))

@@ -3,26 +3,20 @@ check skipped: a sound run is correct, and each fault a cell can have,
 and the lower-precision control, make ``correct`` false."""
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 import harness
 
 BENCH = Path(harness.__file__).resolve().parent
 ROOT = BENCH.parent
 SECONDS = 1.5
-
-
-def tiny(cfg: dict) -> dict:
-    cfg = dict(cfg, hidden_size=64, intermediate_size=128,
-               num_attention_heads=4, num_key_value_heads=2,
-               num_hidden_layers=2, vocab_size=500)
-    cfg["job"] = dict(cfg["job"], seq_len=32, batch=4)
-    return cfg
 
 
 def quick(mix: dict, interval: float) -> dict:
@@ -33,16 +27,25 @@ def run(workload: str, interval: float, bench_dir: Path = BENCH,
         root: Path = ROOT, **kw) -> dict:
     bench = harness.load_benchmark(root)
     cell = harness.cell_spec(bench, workload)
-    cfg = tiny(harness.load_config(cell["config"]))
+    cfg = harness.load_config(cell["config"], bench_dir)
+    cfg = harness.arch_module(cfg, bench_dir).tiny(cfg)
     mix = quick(harness.load_mix(cell["traffic"], bench_dir), interval)
     out = harness.run_cell(cell, cfg, mix, 2 ** 31 + 9, SECONDS, None,
-                           time.perf_counter(), **kw)
-    correct, checks = harness.compare(out["checks"], cfg["name"])
+                           time.perf_counter(), bench_dir=bench_dir, **kw)
+    correct, checks = harness.compare(out["checks"], cfg)
     return {"correct": correct, "checks": checks, "run": out}
 
 
 def over(result: dict) -> list:
     return [k for k, c in result["checks"].items() if c["value"] > c["limit"]]
+
+
+def files_as_they_are(bench_dir: Path) -> None:
+    """The benchmark's configurations, architectures and mixes, copied
+    unchanged into ``bench_dir``, beside which a test adds its own."""
+    for kind in ("configs", "archs", "traffic"):
+        shutil.copytree(BENCH / kind, bench_dir / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"))
 
 
 def test_a_new_mix_is_picked_up_by_name(tmp_path):
@@ -53,7 +56,7 @@ def test_a_new_mix_is_picked_up_by_name(tmp_path):
         "name": "yi-6b.fresh", "config": "yi-6b", "traffic": "fresh",
         "chips": 1, "why": "a mix added as data alone"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    (tmp_path / "traffic").mkdir()
+    files_as_they_are(tmp_path)
     mix = harness.load_mix("int8-device")
     (tmp_path / "traffic" / "fresh.json").write_text(json.dumps(mix))
     r = run("yi-6b.fresh", 0.5, bench_dir=tmp_path, root=tmp_path)
@@ -61,6 +64,51 @@ def test_a_new_mix_is_picked_up_by_name(tmp_path):
     details = r["run"]["details"]
     assert details["saves_in_window"] >= 1
     assert r["checks"]["int8_error_ratio"]["value"] <= 1.0
+
+
+def test_a_new_config_is_picked_up_by_name(tmp_path):
+    """An architecture module, a configuration that names it and carries
+    its own limits, and a workloads entry, and no edit to any file the
+    benchmark has, make a cell the harness runs."""
+    files_as_they_are(tmp_path)
+    (tmp_path / "archs" / "fresh_dense.py").write_text(
+        (BENCH / "archs" / "dense.py").read_text())
+    cfg = dict(harness.load_config("internlm2-20b"), name="fresh",
+               arch="fresh_dense",
+               limits={"loss_gap": 1e-3, "grad1_gap": 1e-3,
+                       "change_gap": 5e-3})
+    (tmp_path / "configs" / "fresh.json").write_text(json.dumps(cfg))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({
+        "name": "fresh", "source": cfg["source"],
+        "file": "perfbench/configs/fresh.json", "reduced": [],
+        "why": "an architecture added as files alone"})
+    bench["workloads"].append({
+        "name": "fresh.train", "config": "fresh", "traffic": "train",
+        "chips": 1, "why": "a configuration added as files alone"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    module = harness.arch_module(cfg, tmp_path)
+    assert Path(module.__file__) == tmp_path / "archs" / "fresh_dense.py"
+    r = run("fresh.train", 600.0, bench_dir=tmp_path, root=tmp_path)
+    assert r["correct"], over(r)
+    # the configuration's own limits are the ones compared
+    assert r["checks"]["grad1_gap"]["limit"] == 1e-3
+
+
+@pytest.mark.parametrize(
+    "config", [c["name"] for c in harness.load_benchmark()["configs"]])
+def test_a_sound_run_is_correct(config, tmp_path):
+    """Each configuration at its architecture's tiny size, through the
+    train mix: its first steps agree with the reference, its steps are
+    finite and its stream is trained exactly once."""
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["workloads"] = [{
+        "name": f"{config}.sound", "config": config, "traffic": "train",
+        "chips": 1, "why": "a sound run of the configuration"}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    r = run(f"{config}.sound", 600.0, root=tmp_path)
+    assert r["correct"], over(r)
+    assert r["run"]["details"]["run"]["steps"] >= 1
 
 
 def test_restores_are_checked(monkeypatch):
